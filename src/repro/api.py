@@ -82,9 +82,8 @@ __all__ = [
 
 KERNEL_TUNING_MODES = ("off", "program", "kernel", "both")
 # compile-farm backends: "auto" keeps the clock-based pick (virtual clock
-# -> deterministic "manual" batches, real clock -> worker threads);
-# "process" opts into child-process compiles for GIL-free serving.
-COMPILE_BACKENDS = ("auto", "thread", "process", "manual")
+# -> deterministic "manual" batches, real clock -> worker threads)
+COMPILE_BACKENDS = ("auto", "thread", "manual")
 
 
 def _canon(spec: Mapping[str, Any]) -> str:
@@ -152,7 +151,7 @@ class TuningConfig:
     async_generation: bool = True     # compile variants off the hot path
     prefetch: int = 1                 # speculative compiles per slot
     compile_workers: "int | str" = 1  # compile-farm pool size (M) or "auto"
-    compile_backend: str = "auto"     # auto | thread | process | manual
+    compile_backend: str = "auto"     # auto | thread | manual
     kernel_tuning: str = "program"    # off | program | kernel | both
     cache_entries: int | None = 256   # generation-cache entry bound
     cache_bytes: int | None = None    # generation-cache byte bound
@@ -399,8 +398,7 @@ class TuningConfig:
                        choices=list(COMPILE_BACKENDS),
                        help="compile-farm backend: auto picks threads "
                             "(or deterministic manual batches under a "
-                            "virtual clock); process isolates compiles "
-                            "in child processes")
+                            "virtual clock)")
         g.add_argument("--gate-mode", default=base.gate_mode,
                        choices=list(GATE_MODES),
                        help="trusted swaps: check gates every variant "
@@ -730,7 +728,6 @@ class TuningSession:
         virtual: tuple | None = None,
         evaluator_factory: Callable[..., Any] | None = None,
         gen_cost_s: "float | Callable[..., float] | None" = None,
-        interpret: bool = True,
         aot: bool = True,
         close_on_scope_exit: bool = False,
         compilette_hook: Callable[[Any], None] | None = None,
@@ -744,7 +741,7 @@ class TuningSession:
         # gate verdicts and wrapped generators
         self._plane_kwargs: dict[str, Any] = dict(
             virtual=virtual, evaluator_factory=evaluator_factory,
-            gen_cost_s=gen_cost_s, interpret=interpret, aot=aot,
+            gen_cost_s=gen_cost_s, aot=aot,
             compilette_hook=compilette_hook)
         self._scope_depth = 0
         self._close_on_scope_exit = bool(close_on_scope_exit)

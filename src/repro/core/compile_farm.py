@@ -20,17 +20,12 @@ prefetches for all registered tuners concurrently:
     kernel are *rejected* (``submit`` returns ``None``, the prefetcher
     just tries again next slot). A tuner's own non-speculative request
     is always admitted: there is at most one per tuner.
-  * **three backends** — ``"thread"`` (default): up to ``workers``
+  * **two backends** — ``"thread"`` (default): up to ``workers``
     daemon threads compile concurrently (XLA's C++ compile releases the
-    GIL for most of its work). ``"process"``: same worker threads, but
-    a compilette exposing the ``process_payload`` protocol has the
-    expensive trace+lower+compile executed in a spawned child process
-    first, so even the GIL-holding tracing phase cannot stall serving;
-    with jax's persistent compilation cache configured the parent's own
-    compile then deserializes instead of recompiling (without it the
-    parent recompiles — transparent in ``process_fallbacks``).
-    ``"manual"``: no threads at all; jobs complete only at explicit
-    ``run_pending()`` calls.
+    GIL for most of its work). ``"manual"``: no threads at all; jobs
+    complete only at explicit ``run_pending()`` calls. Compiles stay in
+    the serving process: it holds the accelerator, and a child process
+    could not load the TPU library beside it.
 
 **Deterministic max-overlap semantics (manual mode).** One
 ``run_pending()`` call completes *up to* ``workers`` jobs, in priority
@@ -66,24 +61,9 @@ from typing import Any, Callable, Mapping
 from repro.core.compilette import Compilette, GenerationTicket
 from repro.core.tuning_space import Point
 
-__all__ = ["AsyncGenerator", "CompileFarm", "run_process_payload"]
+__all__ = ["AsyncGenerator", "CompileFarm"]
 
-_MODES = ("thread", "manual", "process")
-
-
-def run_process_payload(payload: tuple) -> tuple[float, int]:
-    """Child-process entry: resolve and run one compile payload.
-
-    ``payload`` is ``(module, attr, kwargs)`` — everything picklable —
-    naming a module-level callable that performs the compile and returns
-    its measured seconds. Returns ``(seconds, child_pid)``.
-    """
-    import importlib
-    import os
-
-    module, attr, kwargs = payload
-    fn = getattr(importlib.import_module(module), attr)
-    return float(fn(**dict(kwargs))), os.getpid()
+_MODES = ("thread", "manual")
 
 
 class CompileFarm:
@@ -153,16 +133,12 @@ class CompileFarm:
         self._threads: set[threading.Thread] = set()
         self._busy = 0                 # workers currently inside _run
         self._stopping = False
-        self._pool = None              # lazy ProcessPoolExecutor
-        self._pool_mu = threading.Lock()
         self.submitted = 0
         self.completed = 0
         self.failed = 0
         self.speculative_submitted = 0
         self.joined = 0
         self.rejected_speculative = 0
-        self.process_offloaded = 0
-        self.process_fallbacks = 0
         # escapes caught by _run_safe (raises past _run's own generate
         # catch, e.g. a non-canonicalizable point key or a raising
         # speculative charge callback) — each one used to kill a worker
@@ -222,7 +198,7 @@ class CompileFarm:
                 self._threads.discard(me)
 
     def shutdown(self) -> None:
-        """Drain queued jobs, stop the workers, release the process pool.
+        """Drain queued jobs and stop the workers.
 
         The farm stays usable: a later submit respawns workers (matching
         the old single-executor behaviour).
@@ -235,55 +211,9 @@ class CompileFarm:
             t.join(timeout=5.0)
         with self._cv:
             self._stopping = False
-        with self._pool_mu:
-            pool, self._pool = self._pool, None
-        if pool is not None:
-            pool.shutdown(wait=True)
-
-    # ------------------------------------------------------------- process
-    def _process_pool(self):
-        with self._pool_mu:
-            if self._pool is None:
-                import concurrent.futures
-                import multiprocessing
-
-                self._pool = concurrent.futures.ProcessPoolExecutor(
-                    max_workers=self.workers,
-                    mp_context=multiprocessing.get_context("spawn"))
-            return self._pool
-
-    def _offload(self, ticket: GenerationTicket) -> tuple[float, int] | None:
-        """Run the ticket's compile payload in a child process.
-
-        Returns ``(child_seconds, child_pid)``, or ``None`` when the
-        compilette has no payload or the child failed — the caller then
-        compiles in-thread as in "thread" mode (``process_fallbacks``).
-        """
-        payload_fn = getattr(ticket.compilette, "process_payload", None)
-        if payload_fn is None:
-            self.process_fallbacks += 1
-            return None
-        try:
-            payload = payload_fn(ticket.point, ticket.specialization)
-        except Exception:
-            payload = None
-        if payload is None:
-            self.process_fallbacks += 1
-            return None
-        try:
-            fut = self._process_pool().submit(run_process_payload, payload)
-            seconds, pid = fut.result()
-            self.process_offloaded += 1
-            return float(seconds), int(pid)
-        except Exception:
-            self.process_fallbacks += 1
-            return None
 
     # ------------------------------------------------------------- running
     def _run(self, ticket: GenerationTicket) -> None:
-        child: tuple[float, int] | None = None
-        if self.mode == "process":
-            child = self._offload(ticket)
         t0 = time.perf_counter()
         try:
             kern = ticket.compilette.generate(
@@ -306,14 +236,6 @@ class CompileFarm:
                     failed_charge = sim
             except Exception:
                 pass
-        if child is not None and kern is not None:
-            # the child's compile is real compute the budget must see,
-            # on top of whatever the parent's own generate measured
-            kern.generation_time_s += child[0]
-            kern.meta["process_compile_s"] = child[0]
-            kern.meta["process_pid"] = child[1]
-        elif child is not None:
-            failed_charge += child[0]
         try:
             key = ticket.compilette.cache_key(
                 ticket.point, ticket.specialization)
@@ -438,7 +360,7 @@ class CompileFarm:
         """Manual mode: complete up to ``max_jobs`` queued jobs inline —
         one *batch* of ``workers`` jobs by default (the max-overlap model
         of M workers each finishing one compile per pump interval). In
-        priority order; returns jobs completed. No-op in thread/process
+        priority order; returns jobs completed. No-op in thread
         mode (the workers drain the queue themselves)."""
         if self.mode != "manual":
             return 0
@@ -614,8 +536,6 @@ class CompileFarm:
                 "speculative_submitted": self.speculative_submitted,
                 "joined": self.joined,
                 "rejected_speculative": self.rejected_speculative,
-                "process_offloaded": self.process_offloaded,
-                "process_fallbacks": self.process_fallbacks,
                 "worker_errors": self.worker_errors,
                 "in_flight": len(self._inflight),
             }

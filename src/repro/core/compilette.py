@@ -77,22 +77,17 @@ def device_free_memory_bytes() -> int | None:
     """Free bytes on the default accelerator, or ``None`` when unknowable.
 
     Read from the device's ``memory_stats()`` (``bytes_limit`` minus
-    ``bytes_in_use``); CPU backends and older jaxlibs report nothing and
-    return ``None``, which callers treat as "no live pressure signal".
+    ``bytes_in_use``); the CPU backend reports nothing, which callers
+    treat as "no live pressure signal".
     """
-    try:
-        import jax
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        if not stats:
-            return None
-        limit = stats.get("bytes_limit")
-        used = stats.get("bytes_in_use")
-        if limit is None or used is None:
-            return None
-        return max(int(limit) - int(used), 0)
-    except Exception:
+    stats = jax.local_devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    used = stats.get("bytes_in_use")
+    if limit is None or used is None:
         return None
+    return max(int(limit) - int(used), 0)
 
 
 def executable_bytes(fn: Callable[..., Any]) -> int | None:

@@ -38,19 +38,10 @@ def _canon(obj: Any) -> str:
 
 def compiler_version() -> str:
     """jax/jaxlib version pair: tuned points do not survive the compiler."""
-    try:
-        import jax
+    import jax
+    import jaxlib
 
-        jver = getattr(jax, "__version__", "unknown")
-        try:
-            import jaxlib
-
-            lver = getattr(jaxlib, "__version__", jver)
-        except Exception:
-            lver = jver
-        return f"jax{jver}-jaxlib{lver}"
-    except Exception:
-        return "nojax"
+    return f"jax{jax.__version__}-jaxlib{jaxlib.__version__}"
 
 
 def device_fingerprint() -> str:
@@ -58,15 +49,13 @@ def device_fingerprint() -> str:
 
     Tuned points are only transferable between identical devices under
     the same compiler, so the registry key includes platform, device kind
-    and the jax/jaxlib version.
+    and the jax/jaxlib version. A device that cannot be seen raises: a
+    tuner must never key its results to a device it did not identify.
     """
-    try:
-        import jax
+    import jax
 
-        d = jax.devices()[0]
-        return f"{d.platform}:{d.device_kind}:{compiler_version()}"
-    except Exception:
-        return "unknown"
+    d = jax.devices()[0]
+    return f"{d.platform}:{d.device_kind}:{compiler_version()}"
 
 
 def device_fallbacks(device: str) -> tuple[str, ...]:

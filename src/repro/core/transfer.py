@@ -104,18 +104,21 @@ def similarity(a: DeviceTraits, b: DeviceTraits) -> float:
     return math.exp(-d / len(TRAIT_AXES))
 
 
-# Nominal (profile, traits) per platform fingerprint prefix. Real
-# backends have no DeviceProfile; the platform string picks a nominal
-# profile and :func:`calibrated_traits` refines its throughput axes
-# with a cost-model probe against the observed reference time.
+# Nominal profiles for real backends, which have no DeviceProfile.
+# :func:`calibrated_traits` refines the throughput axes with a
+# cost-model probe against the observed reference time. TPUs are keyed
+# by ``device_kind`` (TPU generations differ by integer factors, so a
+# kind not listed here has no nominal); hosts and GPUs by platform.
 _CPU_NOMINAL = dataclasses.replace(
     TPU_V5E, name="cpu-host", vpus=1, mxu_tflops=0.5,
     hbm_gbps=64.0, vmem_kb=1024, grid_step_overhead_ns=40.0)
 _GPU_NOMINAL = dataclasses.replace(
     TPU_V5E, name="gpu-generic", mxu_tflops=90.0, hbm_gbps=900.0,
     vmem_kb=20 * 1024)
+_TPU_KIND_NOMINALS: dict[str, DeviceProfile] = {
+    "TPU v5 lite": TPU_V5E,
+}
 _PLATFORM_NOMINALS: tuple[tuple[str, DeviceProfile], ...] = (
-    ("tpu", TPU_V5E),
     ("gpu", _GPU_NOMINAL),
     ("cuda", _GPU_NOMINAL),
     ("rocm", _GPU_NOMINAL),
@@ -123,21 +126,28 @@ _PLATFORM_NOMINALS: tuple[tuple[str, DeviceProfile], ...] = (
 )
 
 
+def _nominal_profile(device: str | None) -> DeviceProfile | None:
+    """The nominal profile of a ``platform:device_kind:...`` fingerprint."""
+    if not device:
+        return None
+    platform, _, rest = str(device).partition(":")
+    platform = platform.strip().lower()
+    if platform == "tpu":
+        return _TPU_KIND_NOMINALS.get(rest.split(":", 1)[0])
+    for prefix, profile in _PLATFORM_NOMINALS:
+        if platform.startswith(prefix):
+            return profile
+    return None
+
+
 def traits_from_fingerprint(device: str | None) -> DeviceTraits | None:
     """Best-effort traits for a real device fingerprint.
 
-    The fingerprint's platform prefix (``platform:device_kind:...``)
-    selects a nominal profile; unknown platforms yield None (the
-    transfer plane then simply stays cold — never a wrong seed ranked
-    by made-up numbers).
+    Unknown platforms and TPU kinds yield None (the transfer plane then
+    simply stays cold — never a wrong seed ranked by made-up numbers).
     """
-    if not device:
-        return None
-    platform = str(device).split(":", 1)[0].strip().lower()
-    for prefix, profile in _PLATFORM_NOMINALS:
-        if platform.startswith(prefix):
-            return DeviceTraits.from_profile(profile)
-    return None
+    profile = _nominal_profile(device)
+    return None if profile is None else DeviceTraits.from_profile(profile)
 
 
 def device_traits(
@@ -190,10 +200,7 @@ def calibrated_traits(
             or not math.isfinite(observed_score_s)
             or observed_score_s <= 0.0):
         return traits
-    platform = str(device or "").split(":", 1)[0].strip().lower()
-    profile = next(
-        (nominal for prefix, nominal in _PLATFORM_NOMINALS
-         if platform.startswith(prefix)), None)
+    profile = _nominal_profile(device)
     if profile is None:
         return traits
     try:

@@ -216,18 +216,6 @@ def _trip_count(cond: _Computation) -> float:
     return 1.0
 
 
-def compiled_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across jax versions.
-
-    jax 0.4.x returns a one-element list of dicts (one per device
-    partition); current jax returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
 def analyze_hlo(text: str, entry: str | None = None) -> Totals:
     comps = _parse_computations(text)
     if not comps:

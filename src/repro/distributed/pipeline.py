@@ -18,16 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax.shard_map is the current spelling; jax 0.4.x only has the
-# experimental module.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# jax.lax.pvary marks an array device-varying for the newer shard_map
-# replication checker; older jax has no such notion — identity is correct.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 
 def pipeline_apply(
     stage_params,          # pytree, leaves with leading axis S (stages)
@@ -46,8 +36,12 @@ def pipeline_apply(
         idx = jax.lax.axis_index(axis)
         params_me = jax.tree.map(lambda a: a[0], params_local)
         mb_shape = x_local.shape[1:]
-        h = _pvary(jnp.zeros(mb_shape, x_local.dtype), (axis,))
-        outs = _pvary(jnp.zeros((M,) + mb_shape, x_local.dtype), (axis,))
+        # carries differ per stage: mark them device-varying for
+        # shard_map's replication checker
+        h = jax.lax.pcast(jnp.zeros(mb_shape, x_local.dtype), (axis,),
+                          to="varying")
+        outs = jax.lax.pcast(jnp.zeros((M,) + mb_shape, x_local.dtype),
+                             (axis,), to="varying")
         perm = [(i, (i + 1) % S) for i in range(S)]
 
         def tick(t, carry):
@@ -73,7 +67,7 @@ def pipeline_apply(
         outs = jax.lax.psum(outs * mask, axis)
         return outs
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         stage_program,
         mesh=mesh,
         in_specs=(P(axis), P()),

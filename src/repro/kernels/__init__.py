@@ -3,6 +3,23 @@
 # catalog discovers them and builds coordinator-ready KernelCompilettes.
 # See repro/kernels/catalog.py for the ~20-line recipe to add one.
 
+
+def pallas_interpret(interpret: bool | None = None) -> bool:
+    """Whether Pallas kernels run in interpret mode.
+
+    The one place the choice is made, from the backend: on for the CPU
+    backend (where the tests run), never on a TPU, where every kernel is
+    compiled by Mosaic. Kernel wrappers pass their ``interpret`` argument
+    through, ``None`` meaning this choice; only a compile for a described
+    (not attached) chip passes ``interpret=False`` explicitly.
+    """
+    if interpret is not None:
+        return interpret
+    import jax
+
+    return jax.default_backend() == "cpu"
+
+
 from repro.kernels.catalog import (
     KernelCatalog,
     KernelCompilette,
@@ -12,6 +29,7 @@ from repro.kernels.catalog import (
 )
 
 __all__ = [
+    "pallas_interpret",
     "KernelCatalog",
     "KernelCompilette",
     "KernelDef",

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
+from repro.kernels import pallas_interpret
 
 Point = dict[str, Any]
 NEG_INF = -1e30
@@ -88,7 +88,7 @@ def flash_attention_pallas(
     causal: bool = True,
     scale: float | None = None,
     q_offset: int = 0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, Tq, H, Dh = q.shape
     _, Tkv, Hk, _ = k.shape
@@ -124,9 +124,9 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", sem)
         ),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(B, H, Tq, Dh).transpose(0, 2, 1, 3)

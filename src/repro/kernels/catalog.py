@@ -17,7 +17,7 @@ deterministic virtual-clock tests.
     from repro.kernels.catalog import KernelDef
     import jax, jax.numpy as jnp
 
-    def _generate(point, spec, *, interpret=True):
+    def _generate(point, spec, *, interpret=None):
         # close over the point: this is the deGoal specialization analogue
         @jax.jit
         def fn(x):
@@ -52,7 +52,6 @@ import dataclasses
 import hashlib
 import importlib
 import os
-import time
 from typing import Any, Callable, Mapping
 
 from repro.core.compilette import Compilette
@@ -63,7 +62,6 @@ __all__ = [
     "KernelDef",
     "KernelCompilette",
     "KernelCatalog",
-    "compile_in_process",
     "discover_kernels",
     "example_fill",
     "get_catalog",
@@ -94,11 +92,13 @@ def example_fill(shape: tuple[int, ...], dtype: Any, *,
 class KernelDef:
     """Declarative description of one tunable kernel.
 
-    ``generate(point, spec, *, interpret)`` must return the concrete
+    ``generate(point, spec, *, interpret=None)`` must return the concrete
     callable for that tuning point with the spec's run-time constants
-    closed over; ``extract_spec(*call_args, **overrides)`` maps live
-    arguments (shapes/dtypes) to the spec dict that keys tuners, registry
-    entries and generation-cache lines; ``abstract_args(spec)`` /
+    closed over (``interpret=None`` leaves Pallas interpret mode to
+    :func:`repro.kernels.pallas_interpret`);
+    ``extract_spec(*call_args, **overrides)`` maps live arguments
+    (shapes/dtypes) to the spec dict that keys tuners, registry entries
+    and generation-cache lines; ``abstract_args(spec)`` /
     ``example_args(spec)`` rebuild AOT avals / concrete evaluation
     arguments from a spec alone.
     """
@@ -135,9 +135,9 @@ class KernelCompilette(Compilette):
       compiled inside ``_generate`` — ``jit(fn).lower(*avals).compile()``
       — so the *actual XLA compile cost* is measured into
       ``generation_time_s`` (and thus ``gen_spent_s``) instead of
-      polluting the first evaluation. Version-guarded: any lowering
-      failure falls back to the lazy ``jax.jit`` wrapper
-      (``aot_fallbacks`` counts them).
+      polluting the first evaluation. A compile the backend refuses
+      (e.g. a Mosaic VMEM or tiling limit) raises: the tuner records it
+      as a generation failure and quarantines the point.
     * **lazy** (``aot=False``): the paper-faithful behaviour before this
       PR — generation returns the un-lowered jit wrapper and the first
       evaluation pays the compile.
@@ -153,7 +153,6 @@ class KernelCompilette(Compilette):
         defn: KernelDef,
         spec: Mapping[str, Any],
         *,
-        interpret: bool = True,
         aot: bool = True,
         virtual: "tuple[Any, DeviceProfile] | None" = None,
         gen_cost_s: "float | Callable[..., float] | None" = None,
@@ -161,11 +160,9 @@ class KernelCompilette(Compilette):
     ) -> None:
         self.defn = defn
         self.spec = dict(spec)
-        self.interpret = interpret
         self.aot = bool(aot) and virtual is None
         self.virtual = virtual
         self.aot_compiles = 0
-        self.aot_fallbacks = 0
         # correctness gate hooks (read by repro.core.gate.VariantGate):
         # the catalog oracle + tolerances, and an optional scripted
         # verdict ``gate_script(point) -> bool`` — the deterministic
@@ -211,49 +208,14 @@ class KernelCompilette(Compilette):
             return virtual_kernel(
                 clock, self.defn.cost_model(dict(point), spec, profile),
                 tag=dict(point))
-        fn = self.defn.generate(dict(point), spec, interpret=self.interpret)
+        fn = self.defn.generate(dict(point), spec)
         if self.aot and self.defn.abstract_args is not None:
-            fn = self._aot_compile(fn, spec)
-        return fn
-
-    def _aot_compile(self, fn: Callable[..., Any],
-                     spec: Mapping[str, Any]) -> Callable[..., Any]:
-        try:
             import jax
 
             jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-            compiled = jitted.lower(*self.defn.abstract_args(spec)).compile()
+            fn = jitted.lower(*self.defn.abstract_args(spec)).compile()
             self.aot_compiles += 1
-            return compiled
-        except Exception:
-            # older jax without the AOT API, or a backend that refuses to
-            # lower this program ahead of time: degrade to the lazy
-            # wrapper (first evaluation pays the compile, as before)
-            self.aot_fallbacks += 1
-            return fn
-
-    # ----------------------------------------------------- process backend
-    def process_payload(self, point: Point,
-                        specialization: Mapping[str, Any]) -> tuple | None:
-        """Picklable compile job for the farm's ``"process"`` backend.
-
-        ``(module, attr, kwargs)`` naming :func:`compile_in_process`,
-        which re-resolves this kernel from the child's own catalog and
-        AOT-compiles the point there — the GIL-heavy trace/lower phase
-        runs outside the serving process, and with jax's persistent
-        compilation cache configured the parent's subsequent compile
-        deserializes instead of recompiling. ``None`` (fall back to an
-        in-thread compile) for virtual/lazy backends, where generation
-        is cheap by construction.
-        """
-        if self.virtual is not None or not self.aot:
-            return None
-        return ("repro.kernels.catalog", "compile_in_process", {
-            "kernel": self.defn.name,
-            "point": dict(point),
-            "spec": {**self.spec, **dict(specialization)},
-            "interpret": self.interpret,
-        })
+        return fn
 
     # ------------------------------------------------------------- helpers
     def has_valid_points(self) -> bool:
@@ -270,24 +232,6 @@ class KernelCompilette(Compilette):
         if self.defn.example_args is None:
             raise ValueError(f"kernel {self.name!r} declares no example args")
         return self.defn.example_args(self.spec)
-
-
-def compile_in_process(kernel: str, point: Mapping[str, Any],
-                       spec: Mapping[str, Any],
-                       interpret: bool = True) -> float:
-    """Child-process entry for the compile farm's ``"process"`` backend.
-
-    Resolves ``kernel`` from this process's own catalog and AOT-compiles
-    ``point`` — the compiled executable itself stays here (XLA
-    executables don't pickle), but the compile populates jax's
-    persistent compilation cache when one is configured, and the
-    returned wall seconds let the parent charge the true compile cost.
-    """
-    comp = get_catalog().compilette(
-        kernel, spec, interpret=interpret, aot=True)
-    start = time.perf_counter()
-    comp._build(dict(point))
-    return time.perf_counter() - start
 
 
 class KernelCatalog:

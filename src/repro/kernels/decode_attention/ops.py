@@ -88,7 +88,7 @@ def decode_attention_cost_model(
 
 # ---------------------------------------------------------- kernel catalog
 def _catalog_generate(point: Point, spec: dict[str, Any], *,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     del interpret  # chunked-jnp path: nothing to interpret
     kc = int(point["k_chunk"])
 
@@ -120,7 +120,11 @@ def _abstract_args(spec: dict[str, Any]) -> tuple:
 
 
 def _example_args(spec: dict[str, Any]) -> tuple:
-    arrays = tuple(example_fill(s, d, scale=0.1) for s, d in _shapes(spec))
+    # q and k at full amplitude keep the softmax far from uniform: near-
+    # uniform weights average v's zero-mean ramp to almost nothing, and
+    # an output that small would hide a wrong variant from the gate
+    arrays = tuple(example_fill(s, d, scale=scale)
+                   for (s, d), scale in zip(_shapes(spec), (1.0, 1.0, 0.1)))
     return arrays + (jnp.int32(spec["S"]),)
 
 

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
+from repro.kernels import pallas_interpret
 
 Point = dict[str, Any]
 
@@ -82,7 +82,7 @@ def euclid_pallas(
     c: jax.Array,       # (M, D) centers
     point: Point,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     N, D = x.shape
     M, D2 = c.shape
@@ -122,8 +122,8 @@ def euclid_pallas(
         out_specs=pl.BlockSpec((bn, bm), o_map),
         out_shape=jax.ShapeDtypeStruct((N, M), jnp.float32),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x, c)

@@ -181,7 +181,6 @@ def make_euclid_compilette(
     N: int, M: int, D: int,
     *,
     backend: str = "jnp",
-    interpret: bool = True,
     vmem_kb: int = TPU_V5E.vmem_kb,
 ) -> Compilette:
     space = make_space(N, M, D, vmem_kb=vmem_kb)
@@ -193,7 +192,7 @@ def make_euclid_compilette(
         elif backend == "pallas":
             @jax.jit
             def fn(x, c):
-                return euclid_pallas(x, c, point, interpret=interpret)
+                return euclid_pallas(x, c, point)
             return fn
         raise ValueError(f"unknown backend {backend!r}")
 
@@ -228,7 +227,7 @@ def reference_simd(dim: int):
 
 # ---------------------------------------------------------- kernel catalog
 def _catalog_generate(point: Point, spec: dict[str, Any], *,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     return generate_jnp_variant(point, dim=spec["D"])
 
 

@@ -20,8 +20,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
+from repro.kernels import pallas_interpret
 
 Point = dict[str, Any]
 
@@ -45,7 +46,7 @@ def lintra_pallas(
     ab: jax.Array,       # (2, W*bands): row 0 = a tiled, row 1 = b tiled
     point: Point,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     H, WB = x.shape
     bh, bw = point["block_h"], point["block_w"]
@@ -73,8 +74,8 @@ def lintra_pallas(
         ],
         out_specs=pl.BlockSpec((bh, bw), x_map),
         out_shape=jax.ShapeDtypeStruct((H, WB), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x, ab)

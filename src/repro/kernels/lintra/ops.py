@@ -137,7 +137,6 @@ def make_lintra_compilette(
     H: int, W: int, bands: int,
     *,
     backend: str = "jnp",
-    interpret: bool = True,
     vmem_kb: int = TPU_V5E.vmem_kb,
 ) -> Compilette:
     space = make_space(H, W, bands, vmem_kb=vmem_kb)
@@ -150,7 +149,7 @@ def make_lintra_compilette(
         elif backend == "pallas":
             @jax.jit
             def fn(x, ab):
-                return lintra_pallas(x, ab, point, interpret=interpret)
+                return lintra_pallas(x, ab, point)
             return fn
         raise ValueError(f"unknown backend {backend!r}")
 
@@ -184,7 +183,7 @@ def reference_simd(bands: int, width: int):
 
 # ---------------------------------------------------------- kernel catalog
 def _catalog_generate(point: Point, spec: dict[str, Any], *,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     # the jnp backend IS this container's real platform: XLA:CPU emits
     # genuinely different machine code per point
     return generate_jnp_variant(point, bands=spec["bands"], width=spec["W"])

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
+from repro.kernels import pallas_interpret
 
 Point = dict[str, Any]
 
@@ -77,7 +77,7 @@ def matmul_pallas(
     point: Point,
     *,
     out_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """C[M,N] = A[M,K] @ B[K,N] with the variant described by ``point``."""
     M, K = a.shape
@@ -121,10 +121,10 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), o_map),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=scratch_shapes,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(a, b)
 
 

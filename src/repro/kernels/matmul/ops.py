@@ -131,7 +131,6 @@ def make_matmul_compilette(
     M: int, N: int, K: int,
     *,
     dtype=jnp.float32,
-    interpret: bool = True,
     vmem_kb: int = TPU_V5E.vmem_kb,
 ) -> Compilette:
     import jax
@@ -141,7 +140,7 @@ def make_matmul_compilette(
     def generate(point: Point, **spec: Any):
         @jax.jit
         def fn(a, b):
-            return matmul_pallas(a, b, point, out_dtype=jnp.float32, interpret=interpret)
+            return matmul_pallas(a, b, point, out_dtype=jnp.float32)
         return fn
 
     def cost_model(point: Point, spec: dict[str, Any], profile: DeviceProfile) -> float:
@@ -152,10 +151,10 @@ def make_matmul_compilette(
     return Compilette("matmul", space, generate, cost_model=cost_model)
 
 
-def tuned_matmul(a, b, *, point: Point | None = None, interpret: bool = True):
+def tuned_matmul(a, b, *, point: Point | None = None):
     """Public wrapper: run the kernel with a tuned (or default) point."""
     point = dict(DEFAULT_POINT if point is None else point)
-    return matmul_pallas(a, b, point, out_dtype=jnp.float32, interpret=interpret)
+    return matmul_pallas(a, b, point, out_dtype=jnp.float32)
 
 
 # ---------------------------------------------------------- kernel catalog
@@ -166,7 +165,7 @@ def _catalog_space(spec: dict[str, Any]) -> TuningSpace:
 
 
 def _catalog_generate(point: Point, spec: dict[str, Any], *,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     import jax
 
     @jax.jit

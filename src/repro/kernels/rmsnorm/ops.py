@@ -55,14 +55,14 @@ def rmsnorm_cost_model(point: Point, spec: dict[str, Any],
     return t
 
 
-def make_rmsnorm_compilette(N: int, d: int, *, interpret: bool = True,
+def make_rmsnorm_compilette(N: int, d: int, *,
                             vmem_kb: int = TPU_V5E.vmem_kb) -> Compilette:
     space = make_space(N, d, vmem_kb=vmem_kb)
 
     def generate(point: Point, **spec: Any):
         @jax.jit
         def fn(x, w):
-            return rmsnorm_pallas(x, w, point, interpret=interpret)
+            return rmsnorm_pallas(x, w, point)
         return fn
 
     def cost_model(point, spec, profile):
@@ -75,7 +75,7 @@ def make_rmsnorm_compilette(N: int, d: int, *, interpret: bool = True,
 
 # ---------------------------------------------------------- kernel catalog
 def _catalog_generate(point: Point, spec: dict[str, Any], *,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     @jax.jit
     def fn(x, w):
         return rmsnorm_pallas(x, w, point, interpret=interpret)

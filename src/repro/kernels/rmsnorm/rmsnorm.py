@@ -13,8 +13,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
+from repro.kernels import pallas_interpret
 
 Point = dict[str, Any]
 
@@ -32,7 +33,7 @@ def rmsnorm_pallas(
     point: Point,
     *,
     eps: float = 1e-6,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     N, d = x.shape
     rows = min(point.get("block_rows", 128), N)
@@ -46,7 +47,7 @@ def rmsnorm_pallas(
         ],
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, d), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x, w.reshape(1, d))

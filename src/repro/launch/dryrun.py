@@ -23,12 +23,10 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
 from repro.configs import REGISTRY, ALL_SHAPES
 from repro.distributed.roofline import roofline_from
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import build_cell, skip_reason
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "../../../dryrun_artifacts")
@@ -57,7 +55,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
 
     t0 = time.time()
     cell = build_cell(cfg, shape, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             cell.fn,
             in_shardings=cell.in_shardings,
@@ -68,9 +66,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-    from repro.distributed.hlo_analysis import compiled_cost_analysis
     mem = compiled.memory_analysis()
-    cost = compiled_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     print(mem)     # proves it fits
     print({k: cost.get(k) for k in ("flops", "bytes accessed")})
     hlo = compiled.as_text()
@@ -123,6 +120,7 @@ def main() -> None:
     ap.add_argument("--scan-chunk", type=int, default=None)
     ap.add_argument("--scores-bf16", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     overrides = {}
     if args.micro is not None:
         overrides["microbatches"] = args.micro

@@ -2,52 +2,25 @@
 
 Defined as FUNCTIONS so importing this module never touches JAX device
 state (the dry-run sets XLA_FLAGS before any jax import; tests see one
-device).
-
-Version portability: ``jax.sharding.AxisType`` and ``jax.set_mesh``
-appeared after jax 0.4.x. ``_mk``/``set_mesh`` degrade gracefully so the
-same call sites work on both old and new jax.
+device). Every axis is ``AxisType.Auto``: sharding follows the
+in/out shardings and the ``shard`` annotations, not explicit types.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-
-def _mk(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    New jax: ``jax.set_mesh(mesh)``. Old jax (no ``set_mesh``): a
-    ``Mesh`` is itself a context manager that installs the ambient mesh;
-    fall back to a null context if even that is unavailable.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext(mesh)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 v5e pod (256 chips) or 2×16×16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for(n_devices: int, model_axis: int = 2):
     """Small host meshes for tests/examples (e.g. 8 = 4×2)."""
     data = n_devices // model_axis
-    return _mk((data, model_axis), ("data", "model"))
+    return jax.make_mesh((data, model_axis), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -18,6 +18,43 @@ constituent kernels under one shared budget) or ``off``.
 """
 
 import argparse
+from typing import Any, Iterator
+
+
+def request_batch(cfg: Any, req: int, batch: int,
+                  prompt_len: int) -> dict[str, Any]:
+    """Request ``req``'s inputs: random prompt tokens seeded by its index,
+    plus the stub audio/vision inputs the encdec and vlm families take."""
+    import jax
+
+    out = {"tokens": jax.random.randint(
+        jax.random.PRNGKey(req), (batch, prompt_len), 0, cfg.vocab)}
+    if cfg.family == "encdec":
+        out["audio_embeds"] = jax.random.normal(
+            jax.random.PRNGKey(1),
+            (batch, cfg.enc_frames, cfg.d_model)) * 0.05
+    if cfg.family == "vlm":
+        out["vision"] = jax.random.normal(
+            jax.random.PRNGKey(1), (batch, 16, cfg.d_model)) * 0.05
+    return out
+
+
+def serve_requests(cfg: Any, serve: Any, session: Any, *, batch: int,
+                   prompt_len: int, requests: int,
+                   params: Any | None = None) -> Iterator[dict[str, Any]]:
+    """Serve ``requests`` requests through one session, yielding each
+    request's :func:`~repro.runtime.serve_loop.generate` output.
+
+    ``params`` (random weights built by the caller) are shared by every
+    request; without them each request draws its own from the serve seed.
+    """
+    from repro.runtime.serve_loop import generate
+
+    for req in range(requests):
+        inputs = request_batch(cfg, req, batch, prompt_len)
+        if params is not None:
+            inputs["params"] = params
+        yield generate(cfg, inputs, serve, session=session)
 
 
 def main() -> None:
@@ -39,10 +76,12 @@ def main() -> None:
     TuningConfig.add_flags(ap, base=base)
     args = ap.parse_args()
 
-    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.configs import get_config
-    from repro.runtime.serve_loop import ServeConfig, generate
+    from repro.runtime.serve_loop import ServeConfig
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -53,18 +92,9 @@ def main() -> None:
     # session, and generate() emits no "autotune" stats block
     session = TuningSession(tcfg) if tcfg.active else None
 
-    for req in range(args.requests):
-        batch = {"tokens": jax.random.randint(
-            jax.random.PRNGKey(req), (args.batch, args.prompt_len),
-            0, cfg.vocab)}
-        if cfg.family == "encdec":
-            batch["audio_embeds"] = jax.random.normal(
-                jax.random.PRNGKey(1),
-                (args.batch, cfg.enc_frames, cfg.d_model)) * 0.05
-        if cfg.family == "vlm":
-            batch["vision"] = jax.random.normal(
-                jax.random.PRNGKey(1), (args.batch, 16, cfg.d_model)) * 0.05
-        out = generate(cfg, batch, serve, session=session)
+    for req, out in enumerate(serve_requests(
+            cfg, serve, session, batch=args.batch,
+            prompt_len=args.prompt_len, requests=args.requests)):
         line = (f"req {req}: {out['decode_tokens_per_s']:.1f} tok/s, "
                 f"prefill {out['prefill_s']*1e3:.0f} ms")
         if session is not None:

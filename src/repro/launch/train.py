@@ -34,6 +34,10 @@ def main() -> None:
     TuningConfig.add_flags(ap, base=base)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.configs import get_config
     from repro.configs.base import ShapeSpec
     from repro.runtime.train_loop import TrainLoopConfig, train

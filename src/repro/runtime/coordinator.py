@@ -137,6 +137,9 @@ class ManagedTuner:
         out["warm_started"] = self.warm_started
         out["state"] = self.state.value
         out["plane_managed"] = self.plane_managed
+        if self.plane_managed:
+            # catalog kernels compile ahead of time, reference included
+            out["aot_compiles"] = self.tuner.compilette.aot_compiles
         out["transfer_seeds"] = len(self.transfer_seed_keys)
         return out
 
@@ -237,7 +240,7 @@ class TuningCoordinator:
         # virtual (advanceable) clock gets the deterministic "manual"
         # pipeline (one batch of up to ``workers`` jobs completes at the
         # next pump, no sleeps), a real clock gets worker threads. Pass
-        # "thread"/"manual"/"process" to force one. The per-kernel cap —
+        # "thread"/"manual" to force one. The per-kernel cap —
         # a kernel's own request plus its prefetch quota — keeps one
         # kernel's wide space from flooding the farm.
         self.prefetch = max(int(prefetch), 0)

@@ -98,7 +98,6 @@ class KernelTuningPlane:
         *,
         catalog: KernelCatalog | None = None,
         strategies: Mapping[str, str] | None = None,
-        interpret: bool = True,
         aot: bool = True,
         virtual: tuple | None = None,
         gen_cost_s: "float | Callable[..., float] | None" = None,
@@ -110,7 +109,6 @@ class KernelTuningPlane:
         self.coordinator = coordinator
         self.catalog = catalog or get_catalog()
         self.strategies = dict(strategies or {})
-        self.interpret = interpret
         self.aot = aot
         self.virtual = virtual
         self.gen_cost_s = gen_cost_s
@@ -208,8 +206,7 @@ class KernelTuningPlane:
                 name, handle.tuner.compilette, handle.tuner.evaluator,
                 specialization=dict(spec))
         comp = self.catalog.compilette(
-            name, bucketed,
-            interpret=self.interpret, aot=self.aot, virtual=self.virtual,
+            name, bucketed, aot=self.aot, virtual=self.virtual,
             gen_cost_s=self.gen_cost_s)
         if self.compilette_hook is not None:
             self.compilette_hook(comp)

@@ -317,7 +317,12 @@ def _generate_inner(
             pads = [(0, w - g) for g, w in zip(got.shape, want.shape)]
             widened.append(jnp.pad(got, pads))
     cache = tuple(widened)
+    # dispatch is asynchronous: without the sync this would time the
+    # enqueue, not the device's prefill
+    jax.block_until_ready((logits, cache))
     t_prefill = time.perf_counter() - t0
+    # one device-side flag over every step's logits, read once at the end
+    finite = jnp.isfinite(logits).all()
 
     tokens = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
     out_tokens = [tokens]
@@ -346,6 +351,7 @@ def _generate_inner(
     for i in range(serve.max_new_tokens - 1):
         t_step = time.perf_counter()
         logits, cache = decode(params, cache, tokens, jnp.int32(pos0 + i))
+        finite &= jnp.isfinite(logits).all()
         tokens = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
         out_tokens.append(tokens)
         if tuning:
@@ -370,7 +376,10 @@ def _generate_inner(
         "tokens": generated,
         "prefill_s": t_prefill,
         "decode_s": t_decode,
-        "decode_tokens_per_s": B * n_new / t_decode if t_decode > 0 else 0.0,
+        # the first new token comes from prefill; the loop decodes the rest
+        "decode_tokens_per_s": (
+            B * (n_new - 1) / t_decode if t_decode > 0 else 0.0),
+        "logits_finite": bool(finite),
     }
     if tuning:
         session.save()

@@ -275,6 +275,19 @@ def test_config_validation_fails_fast():
         TuningConfig.from_flags(args)
 
 
+def test_config_refuses_process_compile_backend():
+    """Compiles stay in the process that holds the chip: a child could
+    not load the TPU library beside it, so the backend does not exist."""
+    with pytest.raises(ValueError, match="compile_backend must be one of"):
+        TuningConfig(compile_backend="process")
+    with pytest.raises(ValueError, match="compile_backend"):
+        TuningConfig.from_env({"REPRO_TUNE_COMPILE_BACKEND": "process"})
+    parser = argparse.ArgumentParser()
+    TuningConfig.add_flags(parser)
+    with pytest.raises(SystemExit):
+        parser.parse_args(["--compile-backend", "process"])
+
+
 # -------------------------------------------- config round-trip properties
 # one random assignment of every flag-covered knob; slo_quantile is
 # normalized onto slo_s (from_flags rejects a quantile without an SLO)

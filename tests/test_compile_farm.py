@@ -4,12 +4,11 @@ Determinism tests run the ``"manual"`` farm on the ``VirtualClock`` with
 declared compile costs — one ``run_pending()`` completes one *batch* of
 up to ``workers`` jobs in priority order (max-overlap semantics: the
 batch's wall time hides inside the serving interval, the budget is
-billed the full sum, ``gen_stall_s`` stays exactly 0). Thread/process
-backends get targeted concurrency and lifecycle tests.
+billed the full sum, ``gen_stall_s`` stays exactly 0). The thread
+backend gets targeted concurrency and lifecycle tests.
 """
 
 import json
-import os
 import threading
 
 import pytest
@@ -297,52 +296,6 @@ def test_shutdown_leaves_farm_reusable():
             break
         threading.Event().wait(0.001)
     assert t2.done and t2.error is None
-    farm.shutdown()
-
-
-# ------------------------------------------------------- process backend
-def _child_compile(seconds: float) -> float:
-    """Module-level child target (picklable-by-name) for payload tests."""
-    return seconds
-
-
-def test_process_backend_falls_back_without_payload():
-    """A compilette with no process_payload protocol compiles in-thread;
-    the fallback is transparent and counted."""
-    clock = VirtualClock()
-    farm = CompileFarm("process", workers=1)
-    comp = virtual_compilette(clock, "k", space(), cost, gen_cost_s=GEN_COST)
-    t = farm.submit(comp, {"unroll": 1}, {})
-    for _ in range(2000):
-        if t.done:
-            break
-        threading.Event().wait(0.001)
-    assert t.done and t.error is None
-    assert farm.stats()["process_fallbacks"] == 1
-    assert farm.stats()["process_offloaded"] == 0
-    farm.shutdown()
-
-
-@pytest.mark.slow
-def test_process_backend_offloads_to_child_process():
-    """The payload runs in a REAL child (different pid) and its seconds
-    are added to the generation charge."""
-    clock = VirtualClock()
-    farm = CompileFarm("process", workers=1)
-    comp = virtual_compilette(clock, "k", space(), cost, gen_cost_s=GEN_COST)
-    comp.process_payload = lambda point, spec: (
-        "test_compile_farm", "_child_compile", {"seconds": 0.125})
-    t = farm.submit(comp, {"unroll": 1}, {})
-    for _ in range(30000):
-        if t.done:
-            break
-        threading.Event().wait(0.005)
-    assert t.done and t.error is None
-    assert farm.stats()["process_offloaded"] == 1
-    assert t.kern.meta["process_pid"] != os.getpid()
-    assert t.kern.meta["process_compile_s"] == 0.125
-    # declared virtual cost + the child's measured seconds, billed once
-    assert t.gen_charge_s == pytest.approx(GEN_COST + 0.125)
     farm.shutdown()
 
 

@@ -79,20 +79,19 @@ def test_family_lowers_on_8dev_mesh(arch, kind):
     import jax, jax.numpy as jnp
     from repro.configs import REGISTRY
     from repro.configs.base import ShapeSpec
-    from repro.distributed.hlo_analysis import compiled_cost_analysis
-    from repro.launch.mesh import make_mesh_for, set_mesh
+    from repro.launch.mesh import make_mesh_for
     from repro.launch.shapes import build_cell
     cfg = REGISTRY['{arch}'].reduced(n_layers=2, vocab=512)
     cfg = dataclasses.replace(cfg, compute_dtype=jnp.bfloat16)
     shape = ShapeSpec('t', '{kind}', 128, 16)
     mesh = make_mesh_for(8, model_axis=2)
     cell = build_cell(cfg, shape, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                            out_shardings=cell.out_shardings,
                            donate_argnums=cell.donate_argnums
                            ).lower(*cell.args).compile()
-    assert compiled_cost_analysis(compiled)['flops'] > 0
+    assert compiled.cost_analysis()['flops'] > 0
     print('ok')
     """)
 
@@ -105,7 +104,7 @@ def test_train_step_executes_on_8dev_mesh():
     import jax, jax.numpy as jnp
     from repro.configs import REGISTRY
     from repro.configs.base import ShapeSpec
-    from repro.launch.mesh import make_mesh_for, set_mesh
+    from repro.launch.mesh import make_mesh_for
     from repro.launch.shapes import build_cell
     from repro.models.model import build_model
     from repro.models.params import init_tree
@@ -118,7 +117,7 @@ def test_train_step_executes_on_8dev_mesh():
     cell = build_cell(cfg, shape, mesh)
     model = build_model(cfg)
     opt = AdamW()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.device_put(
             init_tree(model.param_defs(), jax.random.PRNGKey(0)),
             cell.in_shardings[0])
@@ -142,9 +141,9 @@ def test_train_step_executes_on_8dev_mesh():
 def test_pipeline_parallel_matches_sequential():
     run8("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.launch.mesh import _mk
+    from jax.sharding import AxisType
     from repro.distributed.pipeline import pipeline_apply
-    mesh = _mk((8,), ('pipe',))
+    mesh = jax.make_mesh((8,), ('pipe',), axis_types=(AxisType.Auto,))
     S, M, mb, d = 8, 4, 16, 32
     Ws = jax.random.normal(jax.random.PRNGKey(0), (S, d, d)) * 0.1
     x = jax.random.normal(jax.random.PRNGKey(1), (M, mb, d))
@@ -163,18 +162,19 @@ def test_elastic_restore_across_mesh_shapes():
     run8("""
     import tempfile
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.checkpoint.checkpointer import Checkpointer
-    from repro.launch.mesh import _mk
 
     state = {'w': jnp.arange(64.0).reshape(8, 8)}
     with tempfile.TemporaryDirectory() as d:
-        mesh1 = _mk((4, 2), ('data', 'model'))
+        mesh1 = jax.make_mesh((4, 2), ('data', 'model'),
+                              axis_types=(AxisType.Auto,) * 2)
         s1 = NamedSharding(mesh1, P('data', 'model'))
         sharded = jax.device_put(state['w'], s1)
         ck = Checkpointer(d)
         ck.save(5, {'w': sharded})
-        mesh2 = _mk((2, 4), ('data', 'model'))
+        mesh2 = jax.make_mesh((2, 4), ('data', 'model'),
+                              axis_types=(AxisType.Auto,) * 2)
         s2 = NamedSharding(mesh2, P('data', 'model'))
         restored, manifest = ck.restore({'w': state['w']},
                                         shardings={'w': s2})

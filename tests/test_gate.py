@@ -420,6 +420,33 @@ def test_gate_rejects_corrupted_variant_on_real_numerics():
     assert gate.checks == 2 and gate.failures == 1
 
 
+@pytest.mark.parametrize("name,spec", [
+    ("attention", {"B": 2, "Tq": 128, "Tkv": 128, "H": 4, "Hk": 4,
+                   "Dh": 128, "causal": True, "dtype": "bfloat16"}),
+    ("decode_attention", {"B": 2, "S": 128, "H": 4, "Hk": 4, "Dh": 128,
+                          "dtype": "bfloat16"}),
+    ("rmsnorm", {"N": 64, "d": 256, "dtype": "bfloat16"}),
+])
+def test_gate_judges_bf16_variants_at_bf16_resolution(name, spec):
+    """A bf16 variant and its float32-computed oracle land a few bf16
+    roundings apart (flash attention feeds bf16 probabilities to its
+    second matmul): the gate passes the correct variant, and still
+    rejects one whose output is 10% off."""
+    import jax.numpy as jnp
+
+    from repro.kernels.catalog import get_catalog
+
+    comp = get_catalog().compilette(name, spec)
+    point = next(iter(comp.space.iter_valid()))
+    kern = comp.generate(point)
+    gate = VariantGate(comp)
+    ok, reason = gate.check(point, kern.fn)
+    assert ok, reason
+    ok, reason = gate.check(point, lambda *a: (
+        kern.fn(*a).astype(jnp.float32) * 1.1).astype(jnp.bfloat16))
+    assert not ok and "err" in reason
+
+
 def test_variant_gate_uses_catalog_oracle_and_tolerance():
     """Real-numerics path: the gate passes the kernel's own reference and
     fails a deliberately wrong function, using KernelDef tolerances."""

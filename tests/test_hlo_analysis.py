@@ -87,15 +87,14 @@ def test_collective_bytes_under_spmd():
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P, NamedSharding
+        from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
         from repro.distributed.hlo_analysis import analyze_hlo
-        from repro.launch.mesh import _mk, set_mesh
-        mesh = _mk((8,), ('model',))
+        mesh = jax.make_mesh((8,), ('model',), axis_types=(AxisType.Auto,))
         w_s = NamedSharding(mesh, P(None, 'model'))
         x_s = NamedSharding(mesh, P())
         def f(x, w):
             return jnp.sum(x @ w, axis=-1)   # contraction forces a psum-ish
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             txt = jax.jit(f, in_shardings=(x_s, w_s)).lower(
                 jax.ShapeDtypeStruct((64, 128), jnp.float32),
                 jax.ShapeDtypeStruct((128, 512), jnp.float32),

@@ -186,6 +186,32 @@ def test_serve_generates_tokens():
     out = generate(cfg, batch, ServeConfig(max_new_tokens=6))
     assert out["tokens"].shape == (2, 6)
     assert out["decode_tokens_per_s"] > 0
+    assert out["logits_finite"] is True
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
+        monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is JAX's own setting and the
+    launchers set no other; without it the cache is <checkout>/.jax_cache,
+    a path fixed by the package's location."""
+    import os
+
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_serve_rwkv_state_decode():

@@ -1,0 +1,85 @@
+"""Real-width compiles of the serve path's kernels for a described v5e.
+
+Each catalog kernel the kernel plane attaches for deepseek-7b (batch 2,
+128-token prompts, 16 new tokens, bf16) is compiled by the TPU compiler
+at the smallest and the largest point of its tuning space, for a chip
+that is described and not attached. A refusal here (a VMEM limit, a
+misaligned tile) is what the chip itself would raise; a Pallas kernel
+must come out as a Mosaic kernel (``tpu_custom_call``), never in
+interpret mode. All of these compiles live in this one file: the TPU
+library may be loaded by one process at a time.
+"""
+
+import dataclasses
+import os
+
+import pytest
+
+PALLAS = ("attention", "matmul", "rmsnorm")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without that chip: keep it out of the cache
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _serve_specs() -> dict:
+    """The specs ``attach_kernels`` registers when serving deepseek-7b."""
+    import jax.numpy as jnp
+
+    from repro.api import serve_tuning_defaults
+    from repro.configs import get_config
+    from repro.models.model import model_kernel_specs
+
+    cfg = dataclasses.replace(get_config("deepseek-7b"),
+                              param_dtype=jnp.bfloat16,
+                              compute_dtype=jnp.bfloat16)
+    lifecycle = serve_tuning_defaults().lifecycle()
+    return dict(model_kernel_specs(
+        cfg, batch=2, seq=lifecycle.bucket_length(128),
+        max_len=lifecycle.bucket_length(128 + 16)))
+
+
+@pytest.mark.parametrize("end", ["smallest", "largest"])
+@pytest.mark.parametrize("name", ["attention", "decode_attention",
+                                  "matmul", "rmsnorm"])
+def test_kernel_compiles_for_v5e_at_deepseek_width(name, end, one_chip):
+    import jax
+
+    from repro.kernels.catalog import get_catalog
+
+    spec = _serve_specs()[name]
+    assert spec["dtype"] == "bfloat16"
+    defn = get_catalog().get(name)
+    valid = list(defn.make_space(spec).iter_valid())
+    point = valid[0] if end == "smallest" else valid[-1]
+    args = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                 for a in defn.abstract_args(spec))
+    fn = defn.generate(dict(point), spec, interpret=False)
+    compiled = fn.lower(*args).compile()
+    has_kernel = "tpu_custom_call" in compiled.as_text()
+    assert has_kernel == (name in PALLAS), (name, point)

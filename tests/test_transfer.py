@@ -128,9 +128,13 @@ def test_device_traits_precedence_and_fingerprints():
     assert device_traits(comp, device="cpu:x") == TRAITS_A
     assert device_traits(comp, profile=TI_F3) == DeviceTraits.from_profile(
         TI_F3)
-    # real backends: platform prefix picks the nominal
-    assert traits_from_fingerprint("tpu:v5e:xla-9") == (
+    # real backends: TPUs by device kind, hosts by platform prefix
+    assert traits_from_fingerprint(
+        "tpu:TPU v5 lite:jax0.9.0-jaxlib0.9.0") == (
         DeviceTraits.from_profile(TPU_V5E))
+    # an unknown TPU kind has no nominal: never priced as a v5e
+    assert traits_from_fingerprint("tpu:TPU v9 future:jax0.9.0") is None
+    assert traits_from_fingerprint("tpu:v5e:xla-9") is None
     assert traits_from_fingerprint("cpu:host") is not None
     assert traits_from_fingerprint("quantum:q1") is None
     assert traits_from_fingerprint(None) is None
