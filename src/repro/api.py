@@ -47,6 +47,7 @@ import os
 import threading
 from typing import Any, Callable, Mapping
 
+from repro.core import telemetry
 from repro.core.compilette import (
     Compilette,
     GenerationCache,
@@ -875,7 +876,9 @@ class TuningSession:
         self.coordinator.save_registry(path)
 
     def stats(self) -> dict[str, Any]:
-        return self.coordinator.stats()
+        """The coordinator's stats, and ``telemetry``: the process's span
+        and counter table (:mod:`repro.core.telemetry`)."""
+        return {**self.coordinator.stats(), "telemetry": telemetry.snapshot()}
 
     def start_thread(self, wake_period_s: float = 0.002) -> None:
         self.coordinator.start_thread(wake_period_s)

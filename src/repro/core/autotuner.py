@@ -36,6 +36,7 @@ import threading
 import time
 from typing import Any, Callable, Sequence
 
+from repro.core import telemetry
 from repro.core.compile_farm import CompileFarm
 from repro.core.compilette import (
     Compilette,
@@ -505,9 +506,10 @@ class OnlineAutotuner:
             # -- synchronous generate+evaluate (paper's original cycle) --
             t0 = self._clock()
             try:
-                kern: GeneratedKernel = self.compilette.generate(
-                    point, **self.specialization
-                )
+                with telemetry.span("tuner.generate"):
+                    kern: GeneratedKernel = self.compilette.generate(
+                        point, **self.specialization
+                    )
             except Exception as e:
                 # Generation failures are holes discovered late: record the
                 # spent time, quarantine the point and move on (the paper's
@@ -583,7 +585,8 @@ class OnlineAutotuner:
         # --- variant gate: oracle check before the point may serve -------
         if self._gate_mode != "off" and self._gate is not None:
             t_gate = self._clock()
-            ok, reason = self._gate.check(point, kern.fn)
+            with telemetry.span("tuner.gate"):
+                ok, reason = self._gate.check(point, kern.fn)
             gate_s = self._clock() - t_gate
             self.accounts.tuning_spent_s += gate_s
             self.accounts.gate_spent_s += gate_s

@@ -58,6 +58,7 @@ import threading
 import time
 from typing import Any, Callable, Mapping
 
+from repro.core import telemetry
 from repro.core.compilette import Compilette, GenerationTicket
 from repro.core.tuning_space import Point
 
@@ -216,8 +217,9 @@ class CompileFarm:
     def _run(self, ticket: GenerationTicket) -> None:
         t0 = time.perf_counter()
         try:
-            kern = ticket.compilette.generate(
-                ticket.point, **ticket.specialization)
+            with telemetry.span("tuner.generate"):
+                kern = ticket.compilette.generate(
+                    ticket.point, **ticket.specialization)
             err = None
         except BaseException as e:  # generation failure = late-found hole
             # drop the traceback: it pins the whole _generate frame

@@ -22,6 +22,8 @@ import dataclasses
 import time
 from typing import Any, Callable, Sequence
 
+from repro.core import telemetry
+
 
 def _block(x: Any) -> None:
     try:
@@ -105,12 +107,18 @@ class Evaluator:
             return self.groups * self.group_size + self.warmup
         return self.real_runs + self.warmup
 
+    @telemetry.traced("tuner.evaluate")
     def evaluate(self, fn: Callable[..., Any], args: Sequence[Any] | None = None) -> Measurement:
         if args is None:
             if self.make_args is None:
                 raise ValueError("no args supplied and no make_args factory")
             args = self.make_args()
         t0 = time.perf_counter()
+        # the inputs may be live serving state whose steps are still queued
+        # on the device: the wait is inside t0 (as the first call's was),
+        # and its span shows how much of the evaluation it was
+        with telemetry.span("tuner.wait_inputs"):
+            _block(args)
         if self.mode == "training":
             score = filtered_training_time(
                 fn, args, groups=self.groups, group_size=self.group_size, warmup=self.warmup

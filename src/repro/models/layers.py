@@ -94,6 +94,7 @@ def layer_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return ((x32 - mu) * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def norm(x: jax.Array, scale: jax.Array, kind: str) -> jax.Array:
     return rms_norm(x, scale) if kind == "rmsnorm" else layer_norm(x, scale)
 
@@ -104,6 +105,7 @@ def rope_freqs(d_head: int, theta: float) -> jax.Array:
     return theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
 
 
+@jax.named_scope("attn.rope")
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """x: (B, T, H, Dh); positions: (B, T) int32. Interleaved pairs."""
     B, T, H, Dh = x.shape
@@ -172,6 +174,7 @@ def attention_defs(cfg: ModelConfig, cross: bool = False) -> dict:
     return defs
 
 
+@jax.named_scope("attn.qkv")
 def qkv_proj(x: jax.Array, p: dict, cfg: ModelConfig):
     q = jnp.einsum("btd,dhk->bthk", x, p["wq"].astype(x.dtype))
     k = jnp.einsum("btd,dhk->bthk", x, p["wk"].astype(x.dtype))
@@ -186,6 +189,7 @@ def qkv_proj(x: jax.Array, p: dict, cfg: ModelConfig):
     return q, k, v
 
 
+@jax.named_scope("attn.out")
 def attn_out(o: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
     out = jnp.einsum("bthk,hkd->btd", o, p["wo"].astype(o.dtype))
     return shard(out, "batch", "seq", "embed")
@@ -218,11 +222,12 @@ def self_attention(
         o = plane.call("attention", q, k, v)
     if o is None:
         qc, kc = plane_attn_chunks(cfg)
-        o = flash_attention_jnp(
-            q, k, v, causal=causal, q_offset=q_offset, window=cfg.window,
-            q_chunk=qc, k_chunk=kc,
-            scores_f32=cfg.attn_scores_f32,
-        )
+        with jax.named_scope("attn.core"):
+            o = flash_attention_jnp(
+                q, k, v, causal=causal, q_offset=q_offset,
+                window=cfg.window, q_chunk=qc, k_chunk=kc,
+                scores_f32=cfg.attn_scores_f32,
+            )
     return attn_out(o, p, cfg)
 
 
@@ -243,11 +248,12 @@ def self_attention_with_cache(
         q = apply_rope(q, pos2d, cfg.rope_theta)
         k = apply_rope(k, pos2d, cfg.rope_theta)
     qc, kc = plane_attn_chunks(cfg)
-    o = flash_attention_jnp(
-        q, k, v, causal=True, window=cfg.window,
-        q_chunk=qc, k_chunk=kc,
-        scores_f32=cfg.attn_scores_f32,
-    )
+    with jax.named_scope("attn.core"):
+        o = flash_attention_jnp(
+            q, k, v, causal=True, window=cfg.window,
+            q_chunk=qc, k_chunk=kc,
+            scores_f32=cfg.attn_scores_f32,
+        )
     return attn_out(o, p, cfg), (k, v)
 
 
@@ -266,6 +272,7 @@ def from_bits(x: jax.Array, like_dtype=jnp.bfloat16) -> jax.Array:
         if x.dtype == jnp.uint16 else x
 
 
+@jax.named_scope("attn.kv_update")
 def _dus_bits(cache: jax.Array, update: jax.Array, start: tuple) -> jax.Array:
     """dynamic_update_slice through a u16 bit-view for bf16 caches.
 
@@ -324,8 +331,9 @@ def decode_self_attention(
         o = plane.call("decode_attention", q, cache_k, cache_v,
                        jnp.asarray(length, jnp.int32))
     if o is None:
-        o = decode_attention(q, cache_k, cache_v, length=length,
-                             k_chunk=plane_decode_chunk(cfg))
+        with jax.named_scope("attn.core"):
+            o = decode_attention(q, cache_k, cache_v, length=length,
+                                 k_chunk=plane_decode_chunk(cfg))
     return attn_out(o, p, cfg), (cache_k, cache_v)
 
 
@@ -373,6 +381,7 @@ def mlp_defs(cfg: ModelConfig, d_ff: int | None = None) -> dict:
     }
 
 
+@jax.named_scope("mlp")
 def mlp(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
     if cfg.act == "swiglu":
         g = jnp.einsum("btd,df->btf", x, p["w_gate"].astype(x.dtype))
@@ -395,11 +404,13 @@ def embedding_defs(cfg: ModelConfig) -> dict:
     }
 
 
+@jax.named_scope("embed")
 def embed_tokens(tokens: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
     x = p["embed"].astype(cfg.compute_dtype)[tokens]
     return shard(x, "batch", "seq", "embed")
 
 
+@jax.named_scope("head")
 def logits_out(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
     logits = jnp.einsum("btd,dv->btv", x, p["unembed"].astype(x.dtype))
     logits = shard(logits, "batch", "seq", "vocab")

@@ -122,8 +122,11 @@ def forward_decode(params, x, cache, pos, cfg: ModelConfig, rope_pos=None):
     def body(carry, inp):
         h, ks, vs = carry
         lp, i = inp
-        ck = L.from_bits(jax.lax.dynamic_index_in_dim(ks, i, 0, keepdims=False))
-        cv = L.from_bits(jax.lax.dynamic_index_in_dim(vs, i, 0, keepdims=False))
+        with jax.named_scope("cache.read"):
+            ck = L.from_bits(
+                jax.lax.dynamic_index_in_dim(ks, i, 0, keepdims=False))
+            cv = L.from_bits(
+                jax.lax.dynamic_index_in_dim(vs, i, 0, keepdims=False))
         hn = L.norm(h, lp["ln1"], cfg.norm)
         attn, (ck, cv) = L.decode_self_attention(
             hn, lp["attn"], cfg, ck, cv, pos, rope_pos=rope_pos)
@@ -134,14 +137,18 @@ def forward_decode(params, x, cache, pos, cfg: ModelConfig, rope_pos=None):
             h = h + attn
             f, _ = _ffn_apply(L.norm(h, lp["ln2"], cfg.norm), lp, cfg)
             h = h + f
-        ks = jax.lax.dynamic_update_index_in_dim(ks, L.to_bits(ck), i, 0)
-        vs = jax.lax.dynamic_update_index_in_dim(vs, L.to_bits(cv), i, 0)
+        with jax.named_scope("cache.write"):
+            ks = jax.lax.dynamic_update_index_in_dim(ks, L.to_bits(ck), i, 0)
+            vs = jax.lax.dynamic_update_index_in_dim(vs, L.to_bits(cv), i, 0)
         return (h, ks, vs), None
 
+    with jax.named_scope("cache.bits"):
+        bits = (L.to_bits(ks), L.to_bits(vs))
     (x, ks, vs), _ = jax.lax.scan(
-        body, (x, L.to_bits(ks), L.to_bits(vs)),
-        (params["layers"], jnp.arange(cfg.n_layers)))
-    return L.norm(x, params["ln_f"], cfg.norm), (L.from_bits(ks), L.from_bits(vs))
+        body, (x, *bits), (params["layers"], jnp.arange(cfg.n_layers)))
+    with jax.named_scope("cache.bits"):
+        cache = (L.from_bits(ks), L.from_bits(vs))
+    return L.norm(x, params["ln_f"], cfg.norm), cache
 
 
 # ------------------------------------------------------------------ model

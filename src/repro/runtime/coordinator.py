@@ -57,6 +57,7 @@ import threading
 import time
 from typing import Any, Callable
 
+from repro.core import telemetry
 from repro.core.autotuner import OnlineAutotuner
 from repro.core.compile_farm import CompileFarm
 from repro.core.compilette import (
@@ -309,6 +310,7 @@ class TuningCoordinator:
             self._last_sync_s = self.clock()
 
     # ------------------------------------------------------------ register
+    @telemetry.traced("tuner.register")
     def register(
         self,
         name: str,
@@ -548,6 +550,7 @@ class TuningCoordinator:
         eligible.sort(key=lambda t: (-t[0], t[1]))
         return [(p, m) for p, _, m in eligible]
 
+    @telemetry.traced("tuner.pump")
     def pump(self) -> bool:
         """One scheduling slot: hand the farm a prioritized batch.
 

@@ -67,6 +67,7 @@ from repro.core import (
     Param,
     clamped_options,
     product_space,
+    telemetry,
 )
 from repro.models.model import build_model
 
@@ -195,6 +196,7 @@ def make_serve_coordinator(serve: ServeConfig, *, clock=None):
     return TuningSession(serve.tuning, clock=clock).coordinator
 
 
+@telemetry.traced("serve.request")
 def generate(
     model_cfg: ModelConfig,
     batch: dict[str, Any],
@@ -208,7 +210,12 @@ def generate(
     legacy ``coordinator=`` argument is adopted into its session; with
     neither, an ephemeral session is built from ``serve.tuning`` and
     closed when the request finishes.
+
+    Returns the tokens, ``first_token_s`` (entry until the prefill's
+    logits are ready), ``prefill_s`` (prefill and cache widening),
+    ``decode_s``, and with tuning on the session's stats (``autotune``).
     """
+    t_entry = time.perf_counter()
     serve = serve or ServeConfig()
     tcfg = serve.tuning
     if tcfg.kernel_tuning not in KERNEL_TUNING_MODES:
@@ -225,55 +232,53 @@ def generate(
         else:
             session = TuningSession(tcfg)
             own_session = True
-    model = build_model(model_cfg)
-    from repro.models.params import init_tree
-    params = batch.pop("params", None)
-    if params is None:
-        params = init_tree(model.param_defs(), jax.random.PRNGKey(serve.seed),
-                           model_cfg.param_dtype)
-
-    B, T = batch["tokens"].shape
-    max_len = T + serve.max_new_tokens
-    if model_cfg.family == "vlm":
-        max_len += model_cfg.vision_patches
-
-    prefill = jax.jit(model.prefill)
-    decode = jax.jit(model.decode_step)
-
-    # ---- online tuning: step-programs + constituent kernels -------------
-    tune_init_s = 0.0
     decode_state: dict[str, Any] = {}
-    if tune_kernels:
-        # Hierarchical registration, kernel level: the model's
-        # constituent Pallas kernels become independent session-managed
-        # compilettes (own space/strategy/registry key), drawing
-        # regeneration slots from the same shared budget as the
-        # step-programs. Untunable shapes (every point a hole at a
-        # reduced size) are skipped, not fatal.
-        t_init = time.perf_counter()
-        session.attach_kernels(model_cfg, batch=B, seq=T, max_len=max_len)
-        tune_init_s += time.perf_counter() - t_init
-    if tune_program:
-        t_init = time.perf_counter()
-        # The compilette's chunk options are bounded by the BUCKETED
-        # extent, matching the bucketed specialization key the
-        # session registers under — so seq 120 and 150 build the
-        # identical 128-bucket space and share one tuner.
-        seq_b = session.coordinator.lifecycle.bucket_length(T)
-        prefill_ev = Evaluator(
-            mode="real", real_runs=1, warmup=1,
-            make_args=lambda: (params, batch))
-        prefill = session.register(
-            "serve_prefill", _prefill_compilette(model_cfg, seq_b),
-            prefill_ev,
-            specialization={"seq": T, "batch": B},
-            reference_fn=prefill,
-        )
-        # register() is idempotent across requests: point the (possibly
-        # pre-existing) evaluator at THIS request's inputs so measurements
-        # stay representative of live traffic.
-        prefill.tuner.evaluator.make_args = prefill_ev.make_args
-        tune_init_s += time.perf_counter() - t_init
+    with telemetry.span("serve.setup"):
+        model = build_model(model_cfg)
+        from repro.models.params import init_tree
+        params = batch.pop("params", None)
+        if params is None:
+            params = init_tree(model.param_defs(),
+                               jax.random.PRNGKey(serve.seed),
+                               model_cfg.param_dtype)
+
+        B, T = batch["tokens"].shape
+        max_len = T + serve.max_new_tokens
+        if model_cfg.family == "vlm":
+            max_len += model_cfg.vision_patches
+
+        prefill = jax.jit(model.prefill)
+        decode = jax.jit(model.decode_step)
+
+        # ---- online tuning: step-programs + constituent kernels ---------
+        if tune_kernels:
+            # Hierarchical registration, kernel level: the model's
+            # constituent Pallas kernels become independent
+            # session-managed compilettes (own space/strategy/registry
+            # key), drawing regeneration slots from the same shared
+            # budget as the step-programs. Untunable shapes (every point
+            # a hole at a reduced size) are skipped, not fatal.
+            session.attach_kernels(model_cfg, batch=B, seq=T,
+                                   max_len=max_len)
+        if tune_program:
+            # The compilette's chunk options are bounded by the BUCKETED
+            # extent, matching the bucketed specialization key the
+            # session registers under — so seq 120 and 150 build the
+            # identical 128-bucket space and share one tuner.
+            seq_b = session.coordinator.lifecycle.bucket_length(T)
+            prefill_ev = Evaluator(
+                mode="real", real_runs=1, warmup=1,
+                make_args=lambda: (params, batch))
+            prefill = session.register(
+                "serve_prefill", _prefill_compilette(model_cfg, seq_b),
+                prefill_ev,
+                specialization={"seq": T, "batch": B},
+                reference_fn=prefill,
+            )
+            # register() is idempotent across requests: point the
+            # (possibly pre-existing) evaluator at THIS request's inputs
+            # so measurements stay representative of live traffic.
+            prefill.tuner.evaluator.make_args = prefill_ev.make_args
 
     # The session scope stays active for the whole request: jitted
     # step-programs traced in here adopt tuned kernel block sizes, and
@@ -285,7 +290,7 @@ def generate(
             return _generate_inner(
                 model_cfg, model, params, batch, serve, session,
                 prefill, decode, B, T, max_len, tuning, tune_program,
-                tune_init_s, decode_state)
+                decode_state, t_entry)
     finally:
         if own_session:
             session.close()
@@ -294,7 +299,7 @@ def generate(
 def _generate_inner(
     model_cfg, model, params, batch, serve, session,
     prefill, decode, B, T, max_len, tuning, tune_program,
-    tune_init_s, decode_state,
+    decode_state, t_entry,
 ) -> dict[str, Any]:
     # Busy-time credit for unmanaged step-programs: with kernel-only
     # tuning the prefill/decode calls are real traffic a busy-time
@@ -303,23 +308,27 @@ def _generate_inner(
     credit_busy = tuning and not tune_program
 
     t0 = time.perf_counter()
-    logits, cache = prefill(params, batch)
-    if credit_busy:
+    with telemetry.span("serve.prefill"):
+        logits, cache = prefill(params, batch)
         jax.block_until_ready(logits)
-        session.observe_busy(time.perf_counter() - t0)
-    # widen KV caches to max_len where the family uses positional caches
-    full = model.init_cache(B, max_len)
-    widened = []
-    for got, want in zip(cache, full):
-        if got.shape == want.shape:
-            widened.append(got)
-        else:
-            pads = [(0, w - g) for g, w in zip(got.shape, want.shape)]
-            widened.append(jnp.pad(got, pads))
-    cache = tuple(widened)
-    # dispatch is asynchronous: without the sync this would time the
-    # enqueue, not the device's prefill
-    jax.block_until_ready((logits, cache))
+    t_first = time.perf_counter()
+    if credit_busy:
+        session.observe_busy(t_first - t0)
+    with telemetry.span("serve.cache_widen"):
+        # widen KV caches to max_len where the family uses positional
+        # caches
+        full = model.init_cache(B, max_len)
+        widened = []
+        for got, want in zip(cache, full):
+            if got.shape == want.shape:
+                widened.append(got)
+            else:
+                pads = [(0, w - g) for g, w in zip(got.shape, want.shape)]
+                widened.append(jnp.pad(got, pads))
+        cache = tuple(widened)
+        # dispatch is asynchronous: without the sync this would time the
+        # enqueue, not the device's prefill
+        jax.block_until_ready((logits, cache))
     t_prefill = time.perf_counter() - t0
     # one device-side flag over every step's logits, read once at the end
     finite = jnp.isfinite(logits).all()
@@ -328,66 +337,77 @@ def _generate_inner(
     out_tokens = [tokens]
     pos0 = T if model_cfg.family != "vlm" else T + model_cfg.vision_patches
 
-    if tune_program:
-        # The decode evaluator replays the *current* decoding state; its
-        # outputs are discarded, so measurement is side-effect-free.
-        t_init = time.perf_counter()
-        decode_state.update(cache=cache, tokens=tokens, pos=jnp.int32(pos0))
-        max_len_b = session.coordinator.lifecycle.bucket_length(max_len)
-        decode_ev = Evaluator(
-            mode="real", real_runs=1, warmup=1,
-            make_args=lambda: (params, decode_state["cache"],
-                               decode_state["tokens"], decode_state["pos"]))
-        decode = session.register(
-            "serve_decode", _decode_compilette(model_cfg, max_len_b),
-            decode_ev,
-            specialization={"max_len": max_len, "batch": B},
-            reference_fn=decode,
-        )
-        decode.tuner.evaluator.make_args = decode_ev.make_args
-        tune_init_s += time.perf_counter() - t_init
+    with telemetry.span("serve.setup"):
+        if tune_program:
+            # The decode evaluator replays the *current* decoding state;
+            # its outputs are discarded, so measurement is
+            # side-effect-free.
+            decode_state.update(cache=cache, tokens=tokens,
+                                pos=jnp.int32(pos0))
+            max_len_b = session.coordinator.lifecycle.bucket_length(max_len)
+            decode_ev = Evaluator(
+                mode="real", real_runs=1, warmup=1,
+                make_args=lambda: (params, decode_state["cache"],
+                                   decode_state["tokens"],
+                                   decode_state["pos"]))
+            decode = session.register(
+                "serve_decode", _decode_compilette(model_cfg, max_len_b),
+                decode_ev,
+                specialization={"max_len": max_len, "batch": B},
+                reference_fn=decode,
+            )
+            decode.tuner.evaluator.make_args = decode_ev.make_args
 
     t1 = time.perf_counter()
-    for i in range(serve.max_new_tokens - 1):
-        t_step = time.perf_counter()
-        logits, cache = decode(params, cache, tokens, jnp.int32(pos0 + i))
-        finite &= jnp.isfinite(logits).all()
-        tokens = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-        out_tokens.append(tokens)
-        if tuning:
-            if credit_busy:
-                # sync before crediting: jax dispatch is asynchronous, so
-                # without it the credited interval would be the enqueue
-                # time (µs) while the device executes inside the final
-                # block_until_ready — and a busy-time budget would starve
-                # exactly the kernel tuning this credit exists to fund
-                jax.block_until_ready(tokens)
-                session.observe_busy(time.perf_counter() - t_step)
-            if tune_program:
-                decode_state.update(
-                    cache=cache, tokens=tokens, pos=jnp.int32(pos0 + i + 1))
-            session.maybe_pump()
-    jax.block_until_ready(tokens)
+    with telemetry.span("serve.decode"):
+        for i in range(serve.max_new_tokens - 1):
+            with telemetry.span("serve.decode_step"):
+                t_step = time.perf_counter()
+                logits, cache = decode(params, cache, tokens,
+                                       jnp.int32(pos0 + i))
+                finite &= jnp.isfinite(logits).all()
+                tokens = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(
+                    jnp.int32)
+                out_tokens.append(tokens)
+                if credit_busy:
+                    # sync before crediting: jax dispatch is asynchronous,
+                    # so without it the credited interval would be the
+                    # enqueue time (µs) while the device executes inside
+                    # the final block_until_ready — and a busy-time
+                    # budget would starve exactly the kernel tuning this
+                    # credit exists to fund
+                    jax.block_until_ready(tokens)
+                    session.observe_busy(time.perf_counter() - t_step)
+            if tuning:
+                if tune_program:
+                    decode_state.update(
+                        cache=cache, tokens=tokens,
+                        pos=jnp.int32(pos0 + i + 1))
+                session.maybe_pump()
+        jax.block_until_ready(tokens)
     t_decode = time.perf_counter() - t1
 
-    generated = jnp.concatenate(out_tokens, axis=1)
-    n_new = generated.shape[1]
-    out = {
-        "tokens": generated,
-        "prefill_s": t_prefill,
-        "decode_s": t_decode,
-        # the first new token comes from prefill; the loop decodes the rest
-        "decode_tokens_per_s": (
-            B * (n_new - 1) / t_decode if t_decode > 0 else 0.0),
-        "logits_finite": bool(finite),
-    }
-    if tuning:
-        session.save()
-        # Lifecycle pass at request end: converged tuners release the
-        # evaluator closures pinning this request's params/batch/cache,
-        # and tuners idle past the eviction horizon are unregistered.
-        session.sweep()
-        out["tune_init_s"] = tune_init_s
-        out["kernel_tuning"] = serve.tuning.kernel_tuning
-        out["autotune"] = session.stats()
-    return out
+    with telemetry.span("serve.finish"):
+        generated = jnp.concatenate(out_tokens, axis=1)
+        n_new = generated.shape[1]
+        out = {
+            "tokens": generated,
+            "first_token_s": t_first - t_entry,
+            "prefill_s": t_prefill,
+            "decode_s": t_decode,
+            # the first new token comes from prefill; the loop decodes
+            # the rest
+            "decode_tokens_per_s": (
+                B * (n_new - 1) / t_decode if t_decode > 0 else 0.0),
+            "logits_finite": bool(finite),
+        }
+        if tuning:
+            session.save()
+            # Lifecycle pass at request end: converged tuners release the
+            # evaluator closures pinning this request's params/batch/
+            # cache, and tuners idle past the eviction horizon are
+            # unregistered.
+            session.sweep()
+            out["kernel_tuning"] = serve.tuning.kernel_tuning
+            out["autotune"] = session.stats()
+        return out
