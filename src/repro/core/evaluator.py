@@ -34,7 +34,14 @@ def _block(x: Any) -> None:
         pass
 
 
-def time_once(fn: Callable[..., Any], args: Sequence[Any]) -> float:
+def time_once(fn: Callable[..., Any],
+              args: Sequence[Any] | Callable[[], Sequence[Any]]) -> float:
+    """Time one call of ``fn`` on ``args``. A callable ``args`` is a
+    factory: it is called for this call's inputs, and its work and the
+    wait for its results stay outside the timed interval."""
+    if callable(args):
+        args = args()
+        _block(args)
     t0 = time.perf_counter()
     out = fn(*args)
     _block(out)
@@ -92,6 +99,7 @@ class Evaluator:
         real_runs: int = 5,
         warmup: int = 1,
         make_args: Callable[[], Sequence[Any]] | None = None,
+        fresh_args: bool = False,
     ) -> None:
         if mode not in ("real", "training"):
             raise ValueError(f"unknown evaluation mode {mode!r}")
@@ -101,6 +109,10 @@ class Evaluator:
         self.real_runs = real_runs
         self.warmup = warmup
         self.make_args = make_args
+        # call make_args once per timed call, warm-up included: for a
+        # program that consumes (donates) its inputs, so no call reuses
+        # a deleted buffer and the live state is never handed over
+        self.fresh_args = fresh_args
 
     def n_runs(self) -> int:
         if self.mode == "training":
@@ -109,6 +121,7 @@ class Evaluator:
 
     @telemetry.traced("tuner.evaluate")
     def evaluate(self, fn: Callable[..., Any], args: Sequence[Any] | None = None) -> Measurement:
+        fresh = args is None and self.fresh_args
         if args is None:
             if self.make_args is None:
                 raise ValueError("no args supplied and no make_args factory")
@@ -119,6 +132,14 @@ class Evaluator:
         # and its span shows how much of the evaluation it was
         with telemetry.span("tuner.wait_inputs"):
             _block(args)
+        if fresh:
+            # the first call takes the inputs already made, each later one
+            # its own (made and waited for outside its interval: time_once)
+            made = [args]
+
+            def call_args() -> Sequence[Any]:
+                return made.pop() if made else self.make_args()
+            args = call_args
         if self.mode == "training":
             score = filtered_training_time(
                 fn, args, groups=self.groups, group_size=self.group_size, warmup=self.warmup

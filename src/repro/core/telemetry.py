@@ -33,7 +33,8 @@ Spans, and what each brackets:
   does not pump opens none).
 - ``tuner.evaluate``: ``Evaluator.evaluate`` of a variant or reference.
 - ``tuner.wait_inputs``: inside ``tuner.evaluate``, the wait for its
-  inputs before the first call: serving work still queued on the device.
+  inputs before the first call: serving work still queued on the device
+  (with ``fresh_args``, and the first call's copy of it).
 - ``tuner.gate``: the oracle gate's check of a measured variant.
 - ``tuner.generate``: ``Compilette.generate`` on a compile-farm worker or
   in a synchronous wake.

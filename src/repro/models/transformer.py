@@ -113,9 +113,12 @@ def forward_prefill(params, x, positions, cfg: ModelConfig):
 def forward_decode(params, x, cache, pos, cfg: ModelConfig, rope_pos=None):
     """One-token decode. x: (B, 1, d); cache: (k, v) with leading L axis.
 
-    The stacked caches ride the scan *carry* (as u16 bit views) and each
-    layer updates its slice in place — one buffer end-to-end, aliased with
-    the donated input cache. ys would double-buffer 2×cache bytes.
+    The stacked caches ride the scan *carry* as they are, and each layer
+    updates its slice in place: with the cache donated (``decode_step``'s
+    argument 1) the carry is one buffer end to end, aliased with the
+    input. ys would double-buffer 2×cache bytes; a bit view of the whole
+    stacked cache would give the carry a padded tile layout on TPU and a
+    full copy of the cache into it and out of it each step.
     """
     ks, vs = cache
 
@@ -123,10 +126,8 @@ def forward_decode(params, x, cache, pos, cfg: ModelConfig, rope_pos=None):
         h, ks, vs = carry
         lp, i = inp
         with jax.named_scope("cache.read"):
-            ck = L.from_bits(
-                jax.lax.dynamic_index_in_dim(ks, i, 0, keepdims=False))
-            cv = L.from_bits(
-                jax.lax.dynamic_index_in_dim(vs, i, 0, keepdims=False))
+            ck = jax.lax.dynamic_index_in_dim(ks, i, 0, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(vs, i, 0, keepdims=False)
         hn = L.norm(h, lp["ln1"], cfg.norm)
         attn, (ck, cv) = L.decode_self_attention(
             hn, lp["attn"], cfg, ck, cv, pos, rope_pos=rope_pos)
@@ -138,17 +139,13 @@ def forward_decode(params, x, cache, pos, cfg: ModelConfig, rope_pos=None):
             f, _ = _ffn_apply(L.norm(h, lp["ln2"], cfg.norm), lp, cfg)
             h = h + f
         with jax.named_scope("cache.write"):
-            ks = jax.lax.dynamic_update_index_in_dim(ks, L.to_bits(ck), i, 0)
-            vs = jax.lax.dynamic_update_index_in_dim(vs, L.to_bits(cv), i, 0)
+            ks = jax.lax.dynamic_update_index_in_dim(ks, ck, i, 0)
+            vs = jax.lax.dynamic_update_index_in_dim(vs, cv, i, 0)
         return (h, ks, vs), None
 
-    with jax.named_scope("cache.bits"):
-        bits = (L.to_bits(ks), L.to_bits(vs))
     (x, ks, vs), _ = jax.lax.scan(
-        body, (x, *bits), (params["layers"], jnp.arange(cfg.n_layers)))
-    with jax.named_scope("cache.bits"):
-        cache = (L.from_bits(ks), L.from_bits(vs))
-    return L.norm(x, params["ln_f"], cfg.norm), cache
+        body, (x, ks, vs), (params["layers"], jnp.arange(cfg.n_layers)))
+    return L.norm(x, params["ln_f"], cfg.norm), (ks, vs)
 
 
 # ------------------------------------------------------------------ model

@@ -164,6 +164,13 @@ def _prefill_compilette(model_cfg: ModelConfig, seq: int) -> Compilette:
                       cache_token=repr(model_cfg))
 
 
+def _decode_program(model_cfg: ModelConfig):
+    """The served decode step. Its cache (argument 1) is donated, so the
+    step updates it in place; the reference and every tuned variant are
+    this kind of program."""
+    return jax.jit(build_model(model_cfg).decode_step, donate_argnums=(1,))
+
+
 def _decode_compilette(model_cfg: ModelConfig, max_len: int) -> Compilette:
     """Points are decode step-programs: flash-decoding KV-chunk variants."""
     space = product_space([
@@ -175,7 +182,7 @@ def _decode_compilette(model_cfg: ModelConfig, max_len: int) -> Compilette:
     def gen(point, **spec):
         cfg2 = dataclasses.replace(
             model_cfg, decode_k_chunk=point["decode_k_chunk"])
-        return jax.jit(build_model(cfg2).decode_step)
+        return _decode_program(cfg2)
 
     return Compilette("serve_decode", space, gen,
                       cache_token=repr(model_cfg))
@@ -248,7 +255,7 @@ def generate(
             max_len += model_cfg.vision_patches
 
         prefill = jax.jit(model.prefill)
-        decode = jax.jit(model.decode_step)
+        decode = _decode_program(model_cfg)
 
         # ---- online tuning: step-programs + constituent kernels ---------
         if tune_kernels:
@@ -339,15 +346,18 @@ def _generate_inner(
 
     with telemetry.span("serve.setup"):
         if tune_program:
-            # The decode evaluator replays the *current* decoding state;
-            # its outputs are discarded, so measurement is
-            # side-effect-free.
+            # The decode evaluator replays the *current* decoding state
+            # on a copy of its cache, one per call (the step consumes the
+            # cache it is given); its outputs are discarded, so
+            # measurement is side-effect-free.
             decode_state.update(cache=cache, tokens=tokens,
                                 pos=jnp.int32(pos0))
             max_len_b = session.coordinator.lifecycle.bucket_length(max_len)
             decode_ev = Evaluator(
-                mode="real", real_runs=1, warmup=1,
-                make_args=lambda: (params, decode_state["cache"],
+                mode="real", real_runs=1, warmup=1, fresh_args=True,
+                make_args=lambda: (params,
+                                   jax.device_put(decode_state["cache"],
+                                                  may_alias=False),
                                    decode_state["tokens"],
                                    decode_state["pos"]))
             decode = session.register(

@@ -6,8 +6,9 @@ at the smallest and the largest point of its tuning space, for a chip
 that is described and not attached. A refusal here (a VMEM limit, a
 misaligned tile) is what the chip itself would raise; a Pallas kernel
 must come out as a Mosaic kernel (``tpu_custom_call``), never in
-interpret mode. All of these compiles live in this one file: the TPU
-library may be loaded by one process at a time.
+interpret mode. The served decode step is compiled there too, to see
+that it updates its cache in place. All of these compiles live in this
+one file: the TPU library may be loaded by one process at a time.
 """
 
 import dataclasses
@@ -83,3 +84,41 @@ def test_kernel_compiles_for_v5e_at_deepseek_width(name, end, one_chip):
     compiled = fn.lower(*args).compile()
     has_kernel = "tpu_custom_call" in compiled.as_text()
     assert has_kernel == (name in PALLAS), (name, point)
+
+
+def test_decode_step_updates_its_cache_in_place_on_v5e(one_chip):
+    """The served decode step at deepseek-7b width and the longest
+    ``ds7b.short`` cache (2885 positions): the donated cache aliases the
+    output, and no op copies or bit-converts a whole stacked cache (a
+    bit view of it gives the scan carry a layout padded four times)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models.model import build_model
+    from repro.models.params import init_tree
+    from repro.runtime.serve_loop import _decode_program
+
+    cfg = dataclasses.replace(get_config("deepseek-7b"), n_layers=2,
+                              param_dtype=jnp.bfloat16,
+                              compute_dtype=jnp.bfloat16)
+    model = build_model(cfg)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(lambda: init_tree(
+        model.param_defs(), jax.random.PRNGKey(0), cfg.param_dtype)))
+    cache = tuple(sds(c) for c in model.init_cache_shape(1, 2885))
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in cache)
+    compiled = _decode_program(cfg).lower(
+        params, cache, sds(jax.ShapeDtypeStruct((1, 1), jnp.int32)),
+        sds(jax.ShapeDtypeStruct((), jnp.int32))).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    whole = "[" + ",".join(map(str, cache[0].shape)) + "]"
+    copies = [line.strip()[:120] for line in compiled.as_text().splitlines()
+              if re.search(r"=\s*\w+" + re.escape(whole)
+                           + r"\S*\s+(copy|bitcast-convert)\(", line)]
+    assert not copies, copies
