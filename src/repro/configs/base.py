@@ -25,17 +25,18 @@ class ModelConfig:
     vocab: int
     d_head: int = 128
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0             # the router's width
     top_k: int = 0
     n_shared_experts: int = 0
-    capacity_factor: float = 1.25
-    moe_group_size: int = 512      # GShard dispatch group length (tokens)
+    experts_held: int = 0          # experts this device holds (0: all) ...
+    first_expert: int = 0          # ... from this one on (expert parallelism)
     # --- SSM / RWKV ---
     ssm_state: int = 16
     ssm_conv: int = 4
     rwkv_head_size: int = 64
     # --- attention details ---
     qkv_bias: bool = False
+    qk_norm: bool = False          # RMSNorm over head_dim on q and k (Qwen3)
     use_rope: bool = True          # False: absolute positions (whisper)
     rope_theta: float = 1e6
     window: int | None = None      # sliding-window attention (tokens)
@@ -69,6 +70,10 @@ class ModelConfig:
         return self.n_heads * self.d_head
 
     @property
+    def n_held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
     def supports_long_decode(self) -> bool:
         """O(1)-state decode (SSM/hybrid) — eligible for long_500k."""
         return self.family in ("rwkv", "hybrid")
@@ -98,7 +103,8 @@ class ModelConfig:
             per_layer += 2 * d * ff         # channel mix (sq-relu)
         elif self.family == "moe":
             n_mat = 3 if self.act == "swiglu" else 2
-            per_layer += self.n_experts * n_mat * d * ff + d * self.n_experts
+            per_layer += (self.n_held_experts * n_mat * d * ff
+                          + d * self.n_experts)
             per_layer += self.n_shared_experts * n_mat * d * ff
         else:
             n_mat = 3 if self.act == "swiglu" else 2
@@ -134,7 +140,6 @@ class ModelConfig:
             n_experts=min(self.n_experts, 8) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
             n_shared_experts=min(self.n_shared_experts, 1),
-            moe_group_size=32,
             ssm_state=8,
             rwkv_head_size=16,
             enc_layers=min(self.enc_layers, 2),

@@ -46,6 +46,11 @@ Counters:
   a thread whose innermost open span is ``<span>``; ``compile.(none)``
   where that thread has no span open.
 - ``compile.request``: the same, for a thread inside ``serve.request``.
+- ``moe.rows``: a MoE model's decode steps, the (token, expert) rows
+  routed to the experts this device holds, summed over layers; counted
+  on the device and added once per request in ``serve.finish``.
+- ``moe.active``: the same steps, the held experts hit at least once,
+  summed over layers: the expert weight reads the steps needed.
 """
 
 from __future__ import annotations

@@ -41,7 +41,6 @@ def default_rules(multi_pod: bool = False, **overrides: Any) -> dict[str, Any]:
         "seq": None,
         "layers": None,
         "state": None,
-        "groups": fsdp,     # MoE dispatch groups follow the batch
         # Activations: the residual (embed) dim stays unsharded — "embed"
         # means FSDP only for *weights*; shard() translates it.
         "act_embed": None,

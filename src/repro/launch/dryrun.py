@@ -113,7 +113,6 @@ def main() -> None:
     ap.add_argument("--tag", default="")
     ap.add_argument("--micro", type=int, default=None,
                     help="override gradient-accumulation factor")
-    ap.add_argument("--moe-group", type=int, default=None)
     ap.add_argument("--remat", default=None, choices=("none", "dots", "full"))
     ap.add_argument("--attn-q-chunk", type=int, default=None)
     ap.add_argument("--attn-k-chunk", type=int, default=None)
@@ -124,8 +123,6 @@ def main() -> None:
     overrides = {}
     if args.micro is not None:
         overrides["microbatches"] = args.micro
-    if args.moe_group is not None:
-        overrides["moe_group_size"] = args.moe_group
     if args.remat is not None:
         overrides["remat"] = args.remat
     if args.attn_q_chunk is not None:

@@ -171,6 +171,9 @@ def attention_defs(cfg: ModelConfig, cross: bool = False) -> dict:
         defs["bq"] = ParamDef((H, Dh), ("heads", None), init="zeros")
         defs["bk"] = ParamDef((Hk, Dh), ("kv", None), init="zeros")
         defs["bv"] = ParamDef((Hk, Dh), ("kv", None), init="zeros")
+    if cfg.qk_norm:
+        defs["q_norm"] = ParamDef((Dh,), (None,), init="ones")
+        defs["k_norm"] = ParamDef((Dh,), (None,), init="ones")
     return defs
 
 
@@ -183,6 +186,10 @@ def qkv_proj(x: jax.Array, p: dict, cfg: ModelConfig):
         q = q + p["bq"].astype(x.dtype)
         k = k + p["bk"].astype(x.dtype)
         v = v + p["bv"].astype(x.dtype)
+    if cfg.qk_norm:       # per head, over head_dim, before RoPE
+        with jax.named_scope("attn.qk_norm"):
+            q = rms_norm(q, p["q_norm"])
+            k = rms_norm(k, p["k_norm"])
     q = shard(q, "batch", "seq", "heads", None)
     k = shard(k, "batch", "seq", "kv", None)
     v = shard(v, "batch", "seq", "kv", None)

@@ -59,10 +59,34 @@ def _remat(fn, cfg: ModelConfig):
     )
 
 
-def _ffn_apply(x, lp, cfg) -> tuple[jax.Array, jax.Array]:
+def _ffn_apply(x, lp, cfg, layer=None
+               ) -> tuple[jax.Array, jax.Array, jax.Array | None]:
+    """(out, aux loss, routing counts); dense layers count nothing."""
     if cfg.family == "moe":
-        return moe_ffn(x, lp["ffn"], cfg)
-    return L.mlp(x, lp["ffn"], cfg), jnp.zeros((), jnp.float32)
+        return moe_ffn(x, lp["ffn"], cfg, layer=layer)
+    return L.mlp(x, lp["ffn"], cfg), jnp.zeros((), jnp.float32), None
+
+
+def _serve_layers(layers: dict, cfg: ModelConfig):
+    """The serving scans' per-layer inputs, and ``unpack`` giving one
+    layer's params and its index into whole expert stacks (None: dense).
+
+    A MoE layer's expert weights reach every layer whole rather than as
+    the scan's slice, and the layer picks its own experts among all
+    layers' (``moe_ffn``'s ``layer``): the ragged dot is a kernel, and XLA
+    copies a sliced operand of a kernel (every held expert of the layer,
+    each step) where a dot reads its slice in place.
+    """
+    if cfg.family != "moe":
+        return layers, lambda lp: (lp, None)
+    ffn = dict(layers["ffn"])
+    stacks = {k: ffn.pop(k) for k in ("w_gate", "w_up", "w_down")}
+
+    def unpack(inp):
+        lp, i = inp
+        return dict(lp, ffn=dict(lp["ffn"], **stacks)), i
+
+    return (dict(layers, ffn=ffn), jnp.arange(cfg.n_layers)), unpack
 
 
 # ---------------------------------------------------------------- forward
@@ -74,11 +98,11 @@ def forward_train(params, x, positions, cfg: ModelConfig):
         hn = L.norm(h, lp["ln1"], cfg.norm)
         attn = L.self_attention(hn, lp["attn"], cfg, positions=positions)
         if cfg.parallel_block:
-            f, a = _ffn_apply(hn, lp, cfg)
+            f, a, _ = _ffn_apply(hn, lp, cfg)
             h = h + attn + f
         else:
             h = h + attn
-            f, a = _ffn_apply(L.norm(h, lp["ln2"], cfg.norm), lp, cfg)
+            f, a, _ = _ffn_apply(L.norm(h, lp["ln2"], cfg.norm), lp, cfg)
             h = h + f
         h = shard(h, "batch", "seq", "embed")
         return (h, aux + a), None
@@ -91,26 +115,31 @@ def forward_train(params, x, positions, cfg: ModelConfig):
 def forward_prefill(params, x, positions, cfg: ModelConfig):
     """Causal forward that also returns stacked (L, B, T, Hk, Dh) KV caches."""
 
-    def body(carry, lp):
+    xs, unpack = _serve_layers(params["layers"], cfg)
+
+    def body(carry, inp):
         h = carry
+        lp, layer = unpack(inp)
         hn = L.norm(h, lp["ln1"], cfg.norm)
         attn, (k, v) = L.self_attention_with_cache(
             hn, lp["attn"], cfg, positions=positions)
         if cfg.parallel_block:
-            f, _ = _ffn_apply(hn, lp, cfg)
+            f, _, _ = _ffn_apply(hn, lp, cfg, layer)
             h = h + attn + f
         else:
             h = h + attn
-            f, _ = _ffn_apply(L.norm(h, lp["ln2"], cfg.norm), lp, cfg)
+            f, _, _ = _ffn_apply(L.norm(h, lp["ln2"], cfg.norm), lp, cfg,
+                                 layer)
             h = h + f
         h = shard(h, "batch", "seq", "embed")
         return h, (k, v)
 
-    x, (ks, vs) = jax.lax.scan(_remat(body, cfg), x, params["layers"])
+    x, (ks, vs) = jax.lax.scan(_remat(body, cfg), x, xs)
     return L.norm(x, params["ln_f"], cfg.norm), (ks, vs)
 
 
-def forward_decode(params, x, cache, pos, cfg: ModelConfig, rope_pos=None):
+def forward_decode(params, x, cache, pos, cfg: ModelConfig, rope_pos=None,
+                   counts: bool = False):
     """One-token decode. x: (B, 1, d); cache: (k, v) with leading L axis.
 
     The stacked caches ride the scan *carry* as they are, and each layer
@@ -119,12 +148,17 @@ def forward_decode(params, x, cache, pos, cfg: ModelConfig, rope_pos=None):
     input. ys would double-buffer 2×cache bytes; a bit view of the whole
     stacked cache would give the carry a padded tile layout on TPU and a
     full copy of the cache into it and out of it each step.
+
+    With ``counts`` (MoE) the carry also sums the layers' routing counts
+    (``moe_ffn``), returned third.
     """
     ks, vs = cache
+    xs, unpack = _serve_layers(params["layers"], cfg)
 
     def body(carry, inp):
-        h, ks, vs = carry
+        h, ks, vs = carry[:3]
         lp, i = inp
+        lp, layer = unpack(lp)
         with jax.named_scope("cache.read"):
             ck = jax.lax.dynamic_index_in_dim(ks, i, 0, keepdims=False)
             cv = jax.lax.dynamic_index_in_dim(vs, i, 0, keepdims=False)
@@ -132,20 +166,28 @@ def forward_decode(params, x, cache, pos, cfg: ModelConfig, rope_pos=None):
         attn, (ck, cv) = L.decode_self_attention(
             hn, lp["attn"], cfg, ck, cv, pos, rope_pos=rope_pos)
         if cfg.parallel_block:
-            f, _ = _ffn_apply(hn, lp, cfg)
+            f, _, c = _ffn_apply(hn, lp, cfg, layer)
             h = h + attn + f
         else:
             h = h + attn
-            f, _ = _ffn_apply(L.norm(h, lp["ln2"], cfg.norm), lp, cfg)
+            f, _, c = _ffn_apply(L.norm(h, lp["ln2"], cfg.norm), lp, cfg,
+                                 layer)
             h = h + f
         with jax.named_scope("cache.write"):
             ks = jax.lax.dynamic_update_index_in_dim(ks, ck, i, 0)
             vs = jax.lax.dynamic_update_index_in_dim(vs, cv, i, 0)
+        if counts:
+            return (h, ks, vs, carry[3] + c), None
         return (h, ks, vs), None
 
-    (x, ks, vs), _ = jax.lax.scan(
-        body, (x, ks, vs), (params["layers"], jnp.arange(cfg.n_layers)))
-    return L.norm(x, params["ln_f"], cfg.norm), (ks, vs)
+    init = (x, ks, vs)
+    if counts:
+        init += (jnp.zeros((2,), jnp.int32),)
+    out, _ = jax.lax.scan(body, init, (xs, jnp.arange(cfg.n_layers)))
+    h = L.norm(out[0], params["ln_f"], cfg.norm)
+    if counts:
+        return h, (out[1], out[2]), out[3]
+    return h, (out[1], out[2])
 
 
 # ------------------------------------------------------------------ model
@@ -185,13 +227,18 @@ class TransformerLM:
         logits = L.logits_out(h[:, -1:], params["tok"], cfg)
         return logits, cache
 
-    def decode_step(self, params, cache, tokens, pos, rope_pos=None):
+    def decode_step(self, params, cache, tokens, pos, rope_pos=None,
+                    counts: bool = False):
+        """→ (logits, cache); with ``counts`` (MoE) also the step's routing
+        counts summed over layers: (token, expert) rows routed to held
+        experts, and held experts hit."""
         cfg = self.cfg
         params = cast_params(params, cfg.compute_dtype)
         x = L.embed_tokens(tokens, params["tok"], cfg)    # (B, 1, d)
-        h, cache = forward_decode(params, x, cache, pos, cfg, rope_pos=rope_pos)
+        h, *rest = forward_decode(params, x, cache, pos, cfg,
+                                  rope_pos=rope_pos, counts=counts)
         logits = L.logits_out(h, params["tok"], cfg)
-        return logits, cache
+        return (logits, *rest)
 
     def init_cache_shape(self, batch: int, max_len: int):
         cfg = self.cfg

@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 import warnings
 from typing import Any
@@ -167,8 +168,12 @@ def _prefill_compilette(model_cfg: ModelConfig, seq: int) -> Compilette:
 def _decode_program(model_cfg: ModelConfig):
     """The served decode step. Its cache (argument 1) is donated, so the
     step updates it in place; the reference and every tuned variant are
-    this kind of program."""
-    return jax.jit(build_model(model_cfg).decode_step, donate_argnums=(1,))
+    this kind of program. A MoE model's step also returns its routing
+    counts (``TransformerLM.decode_step``)."""
+    step = build_model(model_cfg).decode_step
+    if model_cfg.family == "moe":
+        step = functools.partial(step, counts=True)
+    return jax.jit(step, donate_argnums=(1,))
 
 
 def _decode_compilette(model_cfg: ModelConfig, max_len: int) -> Compilette:
@@ -368,13 +373,17 @@ def _generate_inner(
             )
             decode.tuner.evaluator.make_args = decode_ev.make_args
 
+    moe_counts = None     # MoE routing counts, summed on the device
     t1 = time.perf_counter()
     with telemetry.span("serve.decode"):
         for i in range(serve.max_new_tokens - 1):
             with telemetry.span("serve.decode_step"):
                 t_step = time.perf_counter()
-                logits, cache = decode(params, cache, tokens,
-                                       jnp.int32(pos0 + i))
+                logits, cache, *counts = decode(params, cache, tokens,
+                                                jnp.int32(pos0 + i))
+                if counts:
+                    moe_counts = counts[0] if moe_counts is None \
+                        else moe_counts + counts[0]
                 finite &= jnp.isfinite(logits).all()
                 tokens = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(
                     jnp.int32)
@@ -399,6 +408,10 @@ def _generate_inner(
 
     with telemetry.span("serve.finish"):
         generated = jnp.concatenate(out_tokens, axis=1)
+        if moe_counts is not None:
+            rows, active = moe_counts.tolist()
+            telemetry.counter("moe.rows", rows)
+            telemetry.counter("moe.active", active)
         n_new = generated.shape[1]
         out = {
             "tokens": generated,

@@ -122,3 +122,42 @@ def test_decode_step_updates_its_cache_in_place_on_v5e(one_chip):
               if re.search(r"=\s*\w+" + re.escape(whole)
                            + r"\S*\s+(copy|bitcast-convert)\(", line)]
     assert not copies, copies
+
+
+def test_moe_decode_reads_its_experts_in_place_on_v5e(one_chip):
+    """The served decode step of qwen3-moe-30b-a3b at its published widths,
+    as ``qwen3moe.chat.b24`` holds it (16 of 128 experts, batch 24): the
+    expert matmuls are ragged dots, which the TPU compiler makes Mosaic
+    grouped matmuls, and no op copies a layer's held experts out of the
+    stacks (a kernel's sliced operand would be one copy a layer a step)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models.model import build_model
+    from repro.models.params import init_tree
+    from repro.runtime.serve_loop import _decode_program
+
+    cfg = dataclasses.replace(get_config("qwen3-moe-30b-a3b"), n_layers=2,
+                              experts_held=16, param_dtype=jnp.bfloat16,
+                              compute_dtype=jnp.bfloat16)
+    model = build_model(cfg)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(lambda: init_tree(
+        model.param_defs(), jax.random.PRNGKey(0), cfg.param_dtype)))
+    cache = tuple(sds(c) for c in model.init_cache_shape(24, 2885))
+    text = _decode_program(cfg).lower(
+        params, cache, sds(jax.ShapeDtypeStruct((24, 1), jnp.int32)),
+        sds(jax.ShapeDtypeStruct((), jnp.int32))).compile().as_text()
+    grouped = [line for line in text.splitlines()
+               if re.match(r"\s*%ragged-dot-none", line)]
+    assert len(grouped) == 3 and all("tpu_custom_call" in line
+                                     for line in grouped)
+    experts = [line.strip()[:120] for line in text.splitlines()
+               if re.search(r"=\s*bf16\[16,(2048,768|768,2048)\]", line)]
+    assert not experts, experts
