@@ -74,6 +74,11 @@ class ModelConfig:
         return self.experts_held or self.n_experts
 
     @property
+    def attn_layers(self) -> int:
+        """Decoder layers with causal self-attention (RWKV has none)."""
+        return 0 if self.family == "rwkv" else self.n_layers
+
+    @property
     def supports_long_decode(self) -> bool:
         """O(1)-state decode (SSM/hybrid) — eligible for long_500k."""
         return self.family in ("rwkv", "hybrid")

@@ -51,6 +51,14 @@ Counters:
   on the device and added once per request in ``serve.finish``.
 - ``moe.active``: the same steps, the held experts hit at least once,
   summed over layers: the expert weight reads the steps needed.
+- ``attn.prefill_tiles``: a served prefill's fused attention kernel, the
+  tiles of its grid summed over (layer, sequence, KV head); computed on
+  the host from the static shapes after the prefill
+  (``kernels.attention.ops.prefill_kernel_tiles``); zero where the
+  chunked jnp path served (off a TPU, under a window).
+- ``attn.prefill_tiles_run``: the same prefills, the tiles the kernel
+  computed: those that meet the causal lower triangle. The rest were
+  neither fetched nor computed.
 """
 
 from __future__ import annotations

@@ -1,11 +1,12 @@
 """Attention ops: chunked-jnp flash implementation, dispatcher, compilette.
 
-``flash_attention_jnp`` is the framework's memory-efficient attention used
-by every model for train/prefill (O(T·d) live memory, online softmax,
-double-checkpointed so the backward recomputes score blocks). It is also
-the oracle-equivalent path the Pallas kernel is validated against, and the
-path the 512-device dry-run lowers (Pallas does not lower on the CPU
-dry-run; the launcher flips ``impl="pallas"`` on real TPU).
+``flash_attention_jnp`` is the memory-efficient attention (O(T·d) live
+memory, online softmax, double-checkpointed so the backward recomputes
+score blocks) that training, the CPU, windowed attention and the
+512-device dry run use. The serving prefill lowered for a TPU calls the
+fused Pallas kernel ``flash_attention_pallas`` instead
+(``prefill_attention``, called by ``models.layers``); the kernel is
+validated against this path and ``attention_ref``.
 """
 
 from __future__ import annotations
@@ -20,7 +21,8 @@ import jax.numpy as jnp
 from repro.core.compilette import Compilette
 from repro.core.profiles import TPU_V5E, DeviceProfile
 from repro.core.tuning_space import Param, Point, TuningSpace
-from repro.kernels.attention.attention import flash_attention_pallas
+from repro.kernels.attention.attention import (
+    causal_tiles, flash_attention_pallas)
 from repro.kernels.attention.ref import attention_ref
 from repro.kernels.catalog import KernelDef, example_fill
 
@@ -219,6 +221,45 @@ def attention(
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
+# ------------------------------------------------- serving prefill dispatch
+def prefill_kernel_serves(window: int | None, platform: str) -> bool:
+    """Whether the serving prefill's causal attention, lowered for
+    ``platform``, is the fused kernel: on a TPU and without a window,
+    which the kernel does not mask."""
+    return platform == "tpu" and window is None
+
+
+def prefill_attention(q, k, v, *, window: int | None, q_chunk: int,
+                      k_chunk: int, scores_f32: bool = True) -> jax.Array:
+    """The serving prefill's causal attention: the fused kernel at
+    (``q_chunk``, ``k_chunk``) tiles where ``prefill_kernel_serves``, the
+    chunked jnp path elsewhere. The platform is the one the program is
+    lowered for."""
+    chunked = functools.partial(
+        flash_attention_jnp, causal=True, window=window, q_chunk=q_chunk,
+        k_chunk=k_chunk, scores_f32=scores_f32)
+    if not prefill_kernel_serves(window, "tpu"):
+        return chunked(q, k, v)
+    return jax.lax.platform_dependent(
+        q, k, v, default=chunked,
+        tpu=functools.partial(
+            flash_attention_pallas, interpret=False,
+            point={"block_q": q_chunk, "block_kv": k_chunk}))
+
+
+def prefill_kernel_tiles(cfg, platform: str, B: int, T: int,
+                         blocks: tuple[int, int]) -> tuple[int, int]:
+    """The fused kernel's tiles in one prefill of ``B`` sequences of
+    ``T`` positions at ``blocks`` = (q_chunk, k_chunk), and of those the
+    tiles it computes, summed over (attention layer, sequence, KV head)
+    of model config ``cfg``; (0, 0) where the chunked jnp path serves."""
+    if not prefill_kernel_serves(cfg.window, platform):
+        return 0, 0
+    grid, run = causal_tiles(T, T, *blocks)
+    calls = cfg.attn_layers * B * cfg.n_kv_heads
+    return calls * grid, calls * run
+
+
 # ------------------------------------------------------------ tuning space
 def make_space(
     Tq: int, Tkv: int, Dh: int,
@@ -358,8 +399,12 @@ KERNEL = KernelDef(
 __all__ = [
     "DEFAULT_POINT",
     "KERNEL",
+    "causal_tiles",
     "flash_attention_jnp",
     "flash_attention_pallas",
+    "prefill_attention",
+    "prefill_kernel_serves",
+    "prefill_kernel_tiles",
     "decode_attention",
     "attention",
     "attention_ref",

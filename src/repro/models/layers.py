@@ -14,7 +14,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import shard
-from repro.kernels.attention.ops import decode_attention, flash_attention_jnp
+from repro.kernels.attention.ops import (
+    decode_attention, flash_attention_jnp, prefill_attention)
 from repro.models.params import ParamDef
 from repro.runtime.kernel_plane import active_plane
 
@@ -256,11 +257,10 @@ def self_attention_with_cache(
         k = apply_rope(k, pos2d, cfg.rope_theta)
     qc, kc = plane_attn_chunks(cfg)
     with jax.named_scope("attn.core"):
-        o = flash_attention_jnp(
-            q, k, v, causal=True, window=cfg.window,
-            q_chunk=qc, k_chunk=kc,
-            scores_f32=cfg.attn_scores_f32,
-        )
+        # on a TPU the fused kernel: score tiles stay in VMEM and tiles
+        # above the diagonal are skipped
+        o = prefill_attention(q, k, v, window=cfg.window, q_chunk=qc,
+                              k_chunk=kc, scores_f32=cfg.attn_scores_f32)
     return attn_out(o, p, cfg), (k, v)
 
 

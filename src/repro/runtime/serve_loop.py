@@ -70,6 +70,8 @@ from repro.core import (
     product_space,
     telemetry,
 )
+from repro.kernels.attention.ops import prefill_kernel_tiles
+from repro.models.layers import plane_attn_chunks
 from repro.models.model import build_model
 
 __all__ = [
@@ -191,6 +193,27 @@ def _decode_compilette(model_cfg: ModelConfig, max_len: int) -> Compilette:
 
     return Compilette("serve_decode", space, gen,
                       cache_token=repr(model_cfg))
+
+
+def _platform(params: Any) -> str:
+    """The platform the parameters, and so the step programs, live on."""
+    leaf = jax.tree.leaves(params)[0]
+    if isinstance(leaf, jax.Array):
+        return next(iter(leaf.devices())).platform
+    return jax.default_backend()
+
+
+def _prefill_tiles(model_cfg: ModelConfig, prefill: Any, params: Any,
+                   B: int, T: int) -> tuple[int, int]:
+    """``prefill_kernel_tiles`` of one served prefill, at the blocks of
+    the served program: the tuner's point, else the reference's."""
+    point = getattr(getattr(prefill, "tuner", None), "last_served_point",
+                    None)
+    if point:
+        blocks = point["attn_q_chunk"], point["attn_k_chunk"]
+    else:
+        blocks = plane_attn_chunks(model_cfg)
+    return prefill_kernel_tiles(model_cfg, _platform(params), B, T, blocks)
 
 
 def make_serve_coordinator(serve: ServeConfig, *, clock=None):
@@ -348,6 +371,9 @@ def _generate_inner(
     tokens = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
     out_tokens = [tokens]
     pos0 = T if model_cfg.family != "vlm" else T + model_cfg.vision_patches
+    tiles, tiles_run = _prefill_tiles(model_cfg, prefill, params, B, pos0)
+    telemetry.counter("attn.prefill_tiles", tiles)
+    telemetry.counter("attn.prefill_tiles_run", tiles_run)
 
     with telemetry.span("serve.setup"):
         if tune_program:

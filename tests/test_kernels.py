@@ -11,7 +11,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.attention.ops import (
-    attention_ref, decode_attention, flash_attention_jnp,
+    attention_ref, causal_tiles, decode_attention, flash_attention_jnp,
     flash_attention_pallas)
 from repro.kernels.euclid.ops import (
     euclid_pallas, euclid_ref, generate_jnp_variant as euclid_variant,
@@ -130,20 +130,106 @@ def test_lintra_variants(h, w, bands):
 
 
 # --------------------------------------------------------------- attention
-@pytest.mark.parametrize("T,H,Hk,Dh", [(128, 4, 4, 32), (192, 8, 2, 32),
-                                       (96, 6, 3, 64)])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_vs_ref(T, H, Hk, Dh, causal):
-    B = 2
+# (B, T, H, Hk, Dh, block_q, block_kv, causal). Ragged T throughout; the
+# causal cases run B 1 and 3, G = H // Hk of 1, 7 and 8 (the cells' GQA
+# groups), tiles from the prefill tuner's options, and head sizes 32 to
+# 128.
+FLASH_CASES = [
+    (2, 128, 4, 4, 32, 64, 48, True), (2, 128, 4, 4, 32, 64, 48, False),
+    (2, 192, 8, 2, 32, 64, 48, True), (2, 192, 8, 2, 32, 64, 48, False),
+    (2, 96, 6, 3, 64, 64, 48, True), (2, 96, 6, 3, 64, 64, 48, False),
+    (1, 100, 2, 2, 32, 32, 64, True),
+    (3, 77, 7, 1, 128, 32, 32, True),
+    (1, 130, 16, 2, 128, 64, 128, True),
+    (3, 45, 8, 1, 64, 256, 32, True),
+    (3, 200, 2, 2, 128, 128, 256, True),
+    (1, 70, 7, 1, 32, 64, 32, True),
+]
+
+
+@pytest.mark.parametrize("B,T,H,Hk,Dh,bq,bkv,causal", FLASH_CASES)
+def test_flash_attention_vs_ref(B, T, H, Hk, Dh, bq, bkv, causal):
     q = rand((B, T, H, Dh))
     k = rand((B, T, Hk, Dh), key=jax.random.PRNGKey(4))
     v = rand((B, T, Hk, Dh), key=jax.random.PRNGKey(5))
     ref = attention_ref(q, k, v, causal=causal)
-    out = flash_attention_jnp(q, k, v, causal=causal, q_chunk=64, k_chunk=48)
+    out = flash_attention_jnp(q, k, v, causal=causal, q_chunk=bq,
+                              k_chunk=bkv)
     np.testing.assert_allclose(out, ref, rtol=2e-3, atol=2e-3)
     outp = flash_attention_pallas(
-        q, k, v, dict(block_q=64, block_kv=64), causal=causal)
+        q, k, v, dict(block_q=bq, block_kv=bkv), causal=causal)
     np.testing.assert_allclose(outp, ref, rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("B,T,H,Hk,bq,bkv", [(24, 100, 8, 1, 256, 512),
+                                               (1, 45, 7, 1, 32, 32)])
+def test_flash_attention_bf16_vs_ref(B, T, H, Hk, bq, bkv):
+    """bf16 inputs, as served: the kernel's f32 scores and bf16 P·V agree
+    with the chunked jnp path's, and both with the float32 reference."""
+    Dh = 128
+    q = rand((B, T, H, Dh), jnp.bfloat16)
+    k = rand((B, T, Hk, Dh), jnp.bfloat16, key=jax.random.PRNGKey(4))
+    v = rand((B, T, Hk, Dh), jnp.bfloat16, key=jax.random.PRNGKey(5))
+    ref = attention_ref(*(x.astype(jnp.float32) for x in (q, k, v)))
+    out = flash_attention_jnp(q, k, v, q_chunk=bq, k_chunk=bkv)
+    outp = flash_attention_pallas(q, k, v, dict(block_q=bq, block_kv=bkv))
+    assert outp.dtype == jnp.bfloat16
+    outp, out = (np.asarray(x, np.float32) for x in (outp, out))
+    np.testing.assert_allclose(outp, out, rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(outp, ref, rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("T,bq,bkv", [(2844, 256, 512), (100, 32, 64),
+                                      (77, 32, 32), (130, 64, 128),
+                                      (45, 256, 32), (300, 128, 32)])
+def test_causal_tiles_are_those_meeting_the_lower_triangle(T, bq, bkv):
+    bq, bkv = min(bq, T), min(bkv, T)
+    n_q, n_kv = -(-T // bq), -(-T // bkv)
+    # a tile meets the triangle when some row at or after its first key
+    # lies in its q block: its first key <= its last real query
+    meets = sum(1 for iq in range(n_q) for ik in range(n_kv)
+                if ik * bkv <= min((iq + 1) * bq, T) - 1)
+    assert causal_tiles(T, T, bq, bkv) == (n_q * n_kv, meets)
+    if (T, bq, bkv) == (2844, 256, 512):
+        assert meets == 42          # 30 of the 72 tiles skipped
+
+
+def test_prefill_attention_lowers_to_the_kernel_for_tpu_only():
+    """``self_attention_with_cache`` lowered for a TPU carries the fused
+    kernel (a Mosaic custom call); lowered for the CPU it is the chunked
+    jnp path, which computes it here; a window keeps the jnp path."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import layers as L
+    from repro.models.model import build_model
+    from repro.models.params import init_tree
+
+    cfg = get_config("qwen3-moe-30b-a3b").reduced(d_head=128)
+    params = init_tree(build_model(cfg).param_defs(), KEY, cfg.param_dtype)
+    lp = jax.tree.map(lambda a: a[0], params["layers"]["attn"])
+    x = rand((2, 40, cfg.d_model))
+    pos = jnp.arange(40)[None]
+
+    def lowered(c, platform):
+        def f(x, lp):
+            return L.self_attention_with_cache(x, lp, c, positions=pos)[0]
+        return jax.jit(f).trace(x, lp).lower(
+            lowering_platforms=(platform,)).as_text()
+
+    assert "tpu_custom_call" in lowered(cfg, "tpu")
+    assert "tpu_custom_call" not in lowered(cfg, "cpu")
+    windowed = dataclasses.replace(cfg, window=16)
+    assert "tpu_custom_call" not in lowered(windowed, "tpu")
+    q, k, v = L.qkv_proj(x, lp, cfg)
+    q = L.apply_rope(q, pos, cfg.rope_theta)
+    k = L.apply_rope(k, pos, cfg.rope_theta)
+    o = flash_attention_jnp(q, k, v, causal=True, q_chunk=cfg.attn_q_chunk,
+                            k_chunk=cfg.attn_k_chunk)
+    got, (k2, v2) = L.self_attention_with_cache(x, lp, cfg, positions=pos)
+    np.testing.assert_allclose(got, L.attn_out(o, lp, cfg), rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_array_equal(k2, k)
 
 
 def test_flash_attention_window_and_offset():

@@ -229,6 +229,54 @@ def test_generate_opens_its_spans_once_each():
         delta(before, after, "compile.request")["s"])
 
 
+def test_generate_counts_the_prefill_kernel_tiles(monkeypatch):
+    """Each served prefill adds its fused attention tiles to
+    ``attn.prefill_tiles`` and ``attn.prefill_tiles_run``: zero where the
+    chunked jnp path served (here, the CPU), and on a TPU the grid and the
+    causal tiles of every (layer, sequence, KV head), at the served
+    program's blocks."""
+    import types
+
+    from repro.api import TuningSession
+    from repro.configs import REGISTRY
+    from repro.kernels.attention.ops import causal_tiles
+    from repro.runtime import serve_loop
+    from repro.runtime.serve_loop import ServeConfig, generate
+
+    names = ("attn.prefill_tiles", "attn.prefill_tiles_run")
+    cfg = REGISTRY["deepseek-7b"].reduced()
+    B, T = 2, 100
+    batch = {"tokens": jnp.ones((B, T), jnp.int32)}
+    serve = ServeConfig(max_new_tokens=2, autotune=True,
+                        kernel_tuning="program", idle_evict_s=None)
+    session = TuningSession(serve.tuning)
+    try:
+        before = telemetry.snapshot()
+        out = generate(cfg, dict(batch), serve, session=session)
+    finally:
+        session.close()
+    tel = out["autotune"]["telemetry"]
+    assert all(name in tel for name in names)
+    assert [delta(before, tel, n)["n"] for n in names] == [0, 0]
+
+    monkeypatch.setattr(serve_loop, "_platform", lambda params: "tpu")
+    before = telemetry.snapshot()
+    generate(cfg, dict(batch), ServeConfig(max_new_tokens=2, autotune=False))
+    after = telemetry.snapshot()
+    calls = cfg.n_layers * B * cfg.n_kv_heads
+    grid, run = causal_tiles(T, T, cfg.attn_q_chunk, cfg.attn_k_chunk)
+    assert run < grid
+    assert [delta(before, after, n)["n"] for n in names] == \
+        [calls * grid, calls * run]
+    tuned = types.SimpleNamespace(tuner=types.SimpleNamespace(
+        last_served_point={"attn_q_chunk": 64, "attn_k_chunk": 32}))
+    grid, run = causal_tiles(T, T, 64, 32)
+    assert serve_loop._prefill_tiles(cfg, tuned, None, B, T) == \
+        (calls * grid, calls * run)
+    windowed = dataclasses.replace(cfg, window=16)
+    assert serve_loop._prefill_tiles(windowed, tuned, None, B, T) == (0, 0)
+
+
 # ------------------------------------------------------- model step scopes
 SCOPES = ("embed", "attn.qkv", "attn.rope", "attn.core", "attn.out", "mlp",
           "norm", "head")

@@ -161,3 +161,54 @@ def test_moe_decode_reads_its_experts_in_place_on_v5e(one_chip):
     experts = [line.strip()[:120] for line in text.splitlines()
                if re.search(r"=\s*bf16\[16,(2048,768|768,2048)\]", line)]
     assert not experts, experts
+
+
+@pytest.mark.parametrize("name,B,T,chunks", [
+    ("deepseek-7b", 1, 2844, None),
+    ("deepseek-coder-33b", 1, 5403, None),
+    ("qwen3-moe-30b-a3b", 24, 2844, None),
+    ("qwen3-moe-30b-a3b", 24, 100, None),
+    ("deepseek-coder-33b", 1, 45, None),
+    ("deepseek-coder-33b", 1, 5403, (32, 32)),
+])
+def test_prefill_attention_is_one_kernel_on_v5e(name, B, T, chunks,
+                                                one_chip):
+    """The served prefill at the cells' widths and batches (two layers),
+    at their longest prompts, at short ragged ones whose G heads stack at
+    offsets off the sublane tiling, and at the prefill tuner's smallest
+    tiles: attention is the fused Mosaic kernel (GQA groups of 1, 7 and
+    8), and no float32 score block of the program's chunks
+    (``[..., q_chunk, k_chunk]``) is left in it."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models.model import build_model
+    from repro.models.params import init_tree
+
+    cfg = dataclasses.replace(get_config(name), n_layers=2,
+                              param_dtype=jnp.bfloat16,
+                              compute_dtype=jnp.bfloat16)
+    if cfg.family == "moe":
+        cfg = dataclasses.replace(cfg, experts_held=16)
+    if chunks:
+        cfg = dataclasses.replace(cfg, attn_q_chunk=chunks[0],
+                                  attn_k_chunk=chunks[1])
+    model = build_model(cfg)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(lambda: init_tree(
+        model.param_defs(), jax.random.PRNGKey(0), cfg.param_dtype)))
+    text = jax.jit(model.prefill).lower(
+        params, {"tokens": sds(jax.ShapeDtypeStruct((B, T), jnp.int32))}
+    ).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "attn.core" in line]
+    assert len(kernels) == 1
+    scores = re.findall(r"f32\[[0-9,]*\b%d,%d\]" % (
+        cfg.attn_q_chunk, cfg.attn_k_chunk), text)
+    assert not scores, scores
