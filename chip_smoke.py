@@ -6,10 +6,12 @@ The quickest proof that the system runs on the chip. It builds
 deepseek-7b at full width (d_model 4096, 32 heads of 128 with 32 KV
 heads, d_ff 11008, vocab 102400) with bf16 weights drawn from a seed,
 and serves 3 requests (batch 2, 128-token prompts, 16 new tokens)
-through one ``TuningSession`` with ``kernel_tuning="both"`` and
+through one ``TuningSession`` with ``kernel_tuning="program"`` and
 ``gate_mode="check"``: the path ``python -m repro.launch.serve`` takes.
-So the jitted step programs, the kernel plane (matmul, attention,
-rmsnorm, decode_attention) and the variant gate all run on the chip.
+Then it attaches the serve path's catalog kernels (matmul, attention,
+rmsnorm, decode_attention) to the same session and calls each handle
+once on the kernel's example inputs. So the jitted step programs, the
+catalog kernels and the variant gate all run on the chip.
 
 It exits non-zero, without the result line, when JAX finds no TPU, when
 any logits are non-finite, when the gate rejects a kernel's base point
@@ -72,9 +74,10 @@ def _record_variants(variants: list) -> Callable[[Any], None]:
 def run_smoke(cfg: Any, *, batch: int, prompt_len: int, new_tokens: int,
               requests: int, seed: int = 0,
               log: Callable[[str], None] = print) -> dict[str, Any]:
-    """Serve ``cfg`` through one tuning session; return what the checks
-    read: per-request outputs, session stats, every compiled kernel
-    variant, and the gate's verdict on each kernel's base point."""
+    """Serve ``cfg`` through one tuning session, then attach the catalog
+    kernels and call each once; return what the checks read: per-request
+    outputs, session stats, every compiled kernel variant, and the gate's
+    verdict on each kernel's base point."""
     import jax
 
     from repro.api import TuningSession, serve_tuning_defaults
@@ -85,7 +88,7 @@ def run_smoke(cfg: Any, *, batch: int, prompt_len: int, new_tokens: int,
     from repro.runtime.serve_loop import ServeConfig
 
     tcfg = dataclasses.replace(
-        serve_tuning_defaults(), enabled=True, kernel_tuning="both",
+        serve_tuning_defaults(), enabled=True, kernel_tuning="program",
         gate_mode="check")
     serve = ServeConfig(max_new_tokens=new_tokens, seed=seed, tuning=tcfg)
     # drawn in one program: no float32 copy of a bf16 weight is ever live
@@ -105,9 +108,16 @@ def run_smoke(cfg: Any, *, batch: int, prompt_len: int, new_tokens: int,
                                   prompt_len=prompt_len, requests=requests,
                                   params=params):
             outs.append(out)
+        # serving tunes the step programs only: the kernels are attached
+        # here, each handle called once on its catalog example inputs
+        plane = session.attach_kernels(cfg, batch=batch, seq=prompt_len,
+                                       max_len=prompt_len + new_tokens)
+        for handle in plane.handles():
+            jax.block_until_ready(
+                handle(*handle.tuner.compilette.example_call_args()))
         stats = session.stats()
         base_gate = []
-        for handle in session.plane.handles():
+        for handle in plane.handles():
             comp = handle.tuner.compilette
             base = handle.tuner.explorer.base_point
             fn = comp.generate(base, **handle.specialization).fn

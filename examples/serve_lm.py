@@ -4,11 +4,10 @@ any assigned architecture (reduced config so it runs on CPU).
     PYTHONPATH=src python examples/serve_lm.py --arch rwkv6-1.6b --tokens 32
 
 With ``--autotune`` the request streams tokens while one
-:class:`repro.TuningSession` tunes the step-programs and (with
-``--kernel-tuning kernel|both``) their constituent Pallas kernels online;
-``--requests N`` sends N requests through the same session so tuning pays
-off across requests (warm variants, no re-exploration). The tuning flags
-are the canonical ``repro.tune`` set declared by
+:class:`repro.TuningSession` tunes the prefill and decode step-programs
+online; ``--requests N`` sends N requests through the same session so
+tuning pays off across requests (warm variants, no re-exploration). The
+tuning flags are the canonical ``repro.tune`` set declared by
 ``repro.TuningConfig.add_flags``.
 """
 
@@ -42,7 +41,7 @@ def main() -> None:
     tcfg = TuningConfig.from_flags(args, base=base)
     cfg = REGISTRY[args.arch].reduced()
     serve = ServeConfig(max_new_tokens=args.tokens, tuning=tcfg)
-    session = TuningSession(tcfg) if tcfg.active else None
+    session = TuningSession(tcfg) if tcfg.tune_program else None
 
     for req in range(args.requests):
         batch = {
@@ -68,21 +67,13 @@ def main() -> None:
         if session is not None:
             a = out["autotune"]
             lc = a["lifecycle"]
-            print(f"  tuning[{args.strategy}/{args.kernel_tuning}]: "
+            print(f"  tuning[{args.strategy}]: "
                   f"{a['regenerations']} regens {a['swaps']} swaps "
                   f"overhead {a['overhead_frac']*100:.1f}% "
                   f"(budget {a['budget_s']*1e3:.0f} ms, "
                   f"init {a['init_spent_s']*1e3:.0f} ms) "
                   f"tuners {a['n_kernels']} "
                   f"({lc['converged']} converged {lc['retired']} retired)")
-            if args.kernel_tuning in ("kernel", "both"):
-                for name, k in sorted(a["kernels"].items()):
-                    if not k.get("plane_managed"):
-                        continue
-                    print(f"    kernel {name}: {k['strategy']} "
-                          f"{k['regenerations']} regens "
-                          f"gen {k['gen_spent_s']*1e3:.1f} ms "
-                          f"eval {k['eval_spent_s']*1e3:.1f} ms")
     if session is not None:
         session.close()
     if args.requests > 0:

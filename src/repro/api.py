@@ -18,23 +18,18 @@ Tuner"'s one-place searcher configuration):
   * :class:`TuningSession` — owns exactly one
     :class:`~repro.runtime.coordinator.TuningCoordinator` (shared
     budget, warm-start registry, generation cache, async pipeline) and
-    at most one :class:`~repro.runtime.kernel_plane.KernelTuningPlane`;
+    at most one :class:`~repro.runtime.kernel_plane.KernelTuningPlane`,
+    built only when a caller attaches kernels;
   * :meth:`TuningSession.tune` / the :func:`tuned` decorator — wrap any
     jax callable into a coordinator-managed
     :class:`~repro.runtime.coordinator.ManagedTuner` handle: the
     application just keeps calling its function;
-  * :meth:`TuningSession.attach_kernels` — hierarchical registration of
-    a model's constituent catalog kernels (subsumes the serve/train
-    plane wiring);
+  * :meth:`TuningSession.attach_kernels` — registration of a model's
+    constituent catalog kernels as explicit handles (the serve and train
+    loops tune their step programs only and never attach kernels);
   * :meth:`TuningSession.scope` — the one context manager serve/train
-    enter: installs the kernel plane for model code, re-entrant, and
-    (for sessions that own their lifetime) closes exactly once at the
-    outermost exit.
-
-Legacy constructors (``make_serve_coordinator``, the per-loop
-coordinator wiring) delegate here behind ``DeprecationWarning``\\ s; the
-ROADMAP's multi-host registry and model-based search strategies plug
-into this surface without touching call sites again.
+    enter: re-entrant, and (for sessions that own their lifetime)
+    closes exactly once at the outermost exit.
 """
 
 from __future__ import annotations
@@ -61,7 +56,6 @@ from repro.runtime.coordinator import ManagedTuner, TuningCoordinator
 from repro.runtime.kernel_plane import (
     KernelTuningPlane,
     parse_kernel_strategies,
-    use_kernel_plane,
 )
 from repro.runtime.lifecycle import TunerLifecycle, TunerState
 
@@ -71,9 +65,7 @@ __all__ = [
     "TunedFunction",
     "TuningConfig",
     "TuningSession",
-    "apply_tuning_kwargs",
     "default_session",
-    "install_tuning_aliases",
     "serve_tuning_defaults",
     "set_default_session",
     "train_tuning_defaults",
@@ -81,7 +73,7 @@ __all__ = [
     "tuned",
 ]
 
-KERNEL_TUNING_MODES = ("off", "program", "kernel", "both")
+KERNEL_TUNING_MODES = ("off", "program")
 # compile-farm backends: "auto" keeps the clock-based pick (virtual clock
 # -> deterministic "manual" batches, real clock -> worker threads)
 COMPILE_BACKENDS = ("auto", "thread", "manual")
@@ -153,7 +145,7 @@ class TuningConfig:
     prefetch: int = 1                 # speculative compiles per slot
     compile_workers: "int | str" = 1  # compile-farm pool size (M) or "auto"
     compile_backend: str = "auto"     # auto | thread | manual
-    kernel_tuning: str = "program"    # off | program | kernel | both
+    kernel_tuning: str = "program"    # off | program
     cache_entries: int | None = 256   # generation-cache entry bound
     cache_bytes: int | None = None    # generation-cache byte bound
     gate_mode: str = "off"            # off | check | canary (trusted swaps)
@@ -225,17 +217,9 @@ class TuningConfig:
 
     # -------------------------------------------------------- derived views
     @property
-    def active(self) -> bool:
-        """Tuning actually happens (enabled and not mode ``off``)."""
-        return self.enabled and self.kernel_tuning != "off"
-
-    @property
     def tune_program(self) -> bool:
-        return self.active and self.kernel_tuning in ("program", "both")
-
-    @property
-    def tune_kernels(self) -> bool:
-        return self.active and self.kernel_tuning in ("kernel", "both")
+        """The step programs are tuned: enabled and not mode ``off``."""
+        return self.enabled and self.kernel_tuning == "program"
 
     def policy(self) -> RegenerationPolicy:
         return RegenerationPolicy(
@@ -354,9 +338,8 @@ class TuningConfig:
                        help="search strategy for every tuner")
         g.add_argument("--kernel-tuning", default=base.kernel_tuning,
                        choices=list(KERNEL_TUNING_MODES),
-                       help="tuning granularity: whole step-programs, "
-                            "individual Pallas kernels, or both levels "
-                            "hierarchically under one shared budget")
+                       help="off disables tuning even with --autotune; "
+                            "program tunes the whole step programs")
         g.add_argument("--kernel-strategy", action="append", default=[],
                        metavar="KERNEL=STRATEGY",
                        help="per-kernel search strategy override "
@@ -525,40 +508,6 @@ def train_tuning_defaults() -> TuningConfig:
         prefetch=1, kernel_tuning="program")
 
 
-# -------------------------------------------------- legacy field aliasing
-def install_tuning_aliases(cls: type, aliases: Mapping[str, str]) -> type:
-    """Install legacy flat-field properties delegating into ``.tuning``.
-
-    Shared by ``ServeConfig``/``TrainLoopConfig``: each legacy name
-    becomes a read/write property over the embedded :class:`TuningConfig`
-    field, so pre-PR-5 call sites keep working against ONE
-    implementation of the aliasing behaviour.
-    """
-    def make(field: str) -> property:
-        def _get(self: Any) -> Any:
-            return getattr(self.tuning, field)
-
-        def _set(self: Any, value: Any) -> None:
-            setattr(self.tuning, field, value)
-
-        return property(_get, _set)
-
-    for legacy, field in aliases.items():
-        setattr(cls, legacy, make(field))
-    return cls
-
-
-def apply_tuning_kwargs(tuning: TuningConfig, aliases: Mapping[str, str],
-                        legacy: Mapping[str, Any], owner: str) -> None:
-    """Apply legacy flat constructor kwargs onto an embedded config."""
-    unknown = set(legacy) - set(aliases)
-    if unknown:
-        raise TypeError(
-            f"{owner} got unexpected keyword arguments {sorted(unknown)}")
-    for key, value in legacy.items():
-        setattr(tuning, aliases[key], value)
-
-
 # ============================================================ TunedFunction
 class TunedFunction:
     """A jax callable wrapped into coordinator-managed tuner handles.
@@ -721,7 +670,6 @@ class TuningSession:
         self,
         config: TuningConfig | None = None,
         *,
-        coordinator: TuningCoordinator | None = None,
         clock: Callable[[], float] | None = None,
         registry: Any | None = None,
         generation_cache: GenerationCache | None = None,
@@ -748,79 +696,55 @@ class TuningSession:
         self._close_on_scope_exit = bool(close_on_scope_exit)
         self._closed = False
         self._close_mu = threading.Lock()
-        if coordinator is not None:
-            # adopt an existing coordinator (legacy shims): the session
-            # wraps it rather than building a second budget domain
-            self.coordinator = coordinator
-        else:
-            cfg = self.config
-            # the backend knob refines async generation: "auto" keeps the
-            # coordinator's clock-based pick, an explicit backend forces
-            # the farm mode (sync generation ignores both)
-            async_generation: "bool | str" = (
-                cfg.async_generation if cfg.compile_backend == "auto"
-                else (cfg.async_generation and cfg.compile_backend))
-            self.coordinator = TuningCoordinator(
-                policy=cfg.policy(),
-                registry=registry,
-                registry_path=cfg.registry_path,
-                device=device,
-                clock=clock,
-                pump_every=cfg.pump_every,
-                lifecycle=cfg.lifecycle(),
-                strategy=cfg.strategy,
-                async_generation=async_generation,
-                generation_cache=(
-                    generation_cache if generation_cache is not None
-                    else GenerationCache(
-                        max_entries=cfg.cache_entries,
-                        max_bytes=cfg.cache_bytes,
-                        # live device-memory pressure shrinks the byte
-                        # bound; on CPU/virtual backends the probe has no
-                        # signal and the static bound applies unchanged
-                        free_memory_fn=device_free_memory_bytes)),
-                prefetch=cfg.prefetch,
-                compile_workers=cfg.compile_workers,
-                gate_mode=cfg.gate_mode,
-                canary_fraction=cfg.canary_fraction,
-                canary_calls=cfg.canary_calls,
-                gate_rtol=cfg.gate_rtol,
-                gate_atol=cfg.gate_atol,
-                replica_id=cfg.replica_id,
-                replica_count=cfg.replica_count,
-                # a backend OBJECT passed to the session (FleetBus, a
-                # custom RegistryBackend) wins over the config's string
-                # spec; both plug into the same coordinator knob
-                registry_backend=_resolve_backend(
-                    registry_backend if registry_backend is not None
-                    else cfg.registry_backend),
-                sync_every_s=cfg.sync_every_s,
-                transfer=cfg.transfer,
-                transfer_top_k=cfg.transfer_top_k,
-                min_similarity=cfg.min_similarity,
-            )
-        self.coordinator._session = self
-        self._plane: KernelTuningPlane | None = getattr(
-            self.coordinator, "_kernel_plane", None)
+        cfg = self.config
+        # the backend knob refines async generation: "auto" keeps the
+        # coordinator's clock-based pick, an explicit backend forces
+        # the farm mode (sync generation ignores both)
+        async_generation: "bool | str" = (
+            cfg.async_generation if cfg.compile_backend == "auto"
+            else (cfg.async_generation and cfg.compile_backend))
+        self.coordinator = TuningCoordinator(
+            policy=cfg.policy(),
+            registry=registry,
+            registry_path=cfg.registry_path,
+            device=device,
+            clock=clock,
+            pump_every=cfg.pump_every,
+            lifecycle=cfg.lifecycle(),
+            strategy=cfg.strategy,
+            async_generation=async_generation,
+            generation_cache=(
+                generation_cache if generation_cache is not None
+                else GenerationCache(
+                    max_entries=cfg.cache_entries,
+                    max_bytes=cfg.cache_bytes,
+                    # live device-memory pressure shrinks the byte
+                    # bound; on CPU/virtual backends the probe has no
+                    # signal and the static bound applies unchanged
+                    free_memory_fn=device_free_memory_bytes)),
+            prefetch=cfg.prefetch,
+            compile_workers=cfg.compile_workers,
+            gate_mode=cfg.gate_mode,
+            canary_fraction=cfg.canary_fraction,
+            canary_calls=cfg.canary_calls,
+            gate_rtol=cfg.gate_rtol,
+            gate_atol=cfg.gate_atol,
+            replica_id=cfg.replica_id,
+            replica_count=cfg.replica_count,
+            # a backend OBJECT passed to the session (FleetBus, a
+            # custom RegistryBackend) wins over the config's string
+            # spec; both plug into the same coordinator knob
+            registry_backend=_resolve_backend(
+                registry_backend if registry_backend is not None
+                else cfg.registry_backend),
+            sync_every_s=cfg.sync_every_s,
+            transfer=cfg.transfer,
+            transfer_top_k=cfg.transfer_top_k,
+            min_similarity=cfg.min_similarity,
+        )
+        self._plane: KernelTuningPlane | None = None
 
     # ------------------------------------------------------------- builders
-    @classmethod
-    def adopt(cls, coordinator: TuningCoordinator,
-              config: TuningConfig | None = None) -> "TuningSession":
-        """The session of ``coordinator``, created (once) on first use.
-
-        Legacy call sites hold bare coordinators; this keeps them on the
-        one-session-per-coordinator invariant. A fresh ``config``
-        refreshes the session's declarative knobs (e.g. a request that
-        switches ``kernel_tuning`` mode).
-        """
-        session = getattr(coordinator, "_session", None)
-        if session is not None:
-            if config is not None:
-                session.config = config
-            return session
-        return cls(config, coordinator=coordinator)
-
     @classmethod
     def from_env(cls, environ: Mapping[str, str] | None = None,
                  *, base: TuningConfig | None = None,
@@ -928,13 +852,14 @@ class TuningSession:
                        ) -> KernelTuningPlane:
         """Register a model's constituent catalog kernels on the plane.
 
-        Subsumes the PR-4 serve/train plane wiring: builds (or refreshes)
-        the coordinator's one shared plane, pre-buckets the traffic
-        extents, and registers every
+        Builds (or refreshes) the coordinator's one shared plane,
+        pre-buckets the traffic extents, and registers every
         :func:`~repro.models.model.model_kernel_specs` kernel —
         including the decode-path ``decode_attention`` keyed per
         cache-length bucket when ``max_len`` is given. Untunable reduced
-        shapes are skipped, not fatal. Idempotent per traffic cell.
+        shapes are skipped, not fatal. Idempotent per traffic cell. The
+        handles serve only calls routed through them
+        (:meth:`KernelTuningPlane.call`, or calling a handle).
         """
         from repro.models.model import model_kernel_specs
 
@@ -943,9 +868,6 @@ class TuningSession:
             self.coordinator,
             strategies=(dict(strategies) if strategies is not None
                         else cfg.strategies),
-            # program points own the chunk knobs in "both" mode: the two
-            # levels must never fight over one knob
-            adopt_points=cfg.kernel_tuning != "both",
             **self._plane_kwargs)
         lifecycle = self.coordinator.lifecycle
         seq_b = lifecycle.bucket_length(int(seq))
@@ -961,20 +883,16 @@ class TuningSession:
     def scope(self):
         """The one context serve/train enter around their request/loop.
 
-        Installs the kernel plane for model code (when kernels are
-        attached), re-entrantly: nested scopes — a serve request inside
-        an outer CLI scope — stack, and a session constructed with
+        Re-entrant: nested scopes — a serve request inside an outer CLI
+        scope — stack, and a session constructed with
         ``close_on_scope_exit=True`` closes exactly once, at the
-        OUTERMOST exit (the regression PR 5's satellite fix covers).
+        OUTERMOST exit.
         """
         if self._closed:
             raise RuntimeError("TuningSession is closed")
         self._scope_depth += 1
-        ctx = (use_kernel_plane(self._plane) if self._plane is not None
-               else contextlib.nullcontext())
         try:
-            with ctx:
-                yield self
+            yield self
         finally:
             self._scope_depth -= 1
             if self._scope_depth == 0 and self._close_on_scope_exit:

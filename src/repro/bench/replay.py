@@ -713,7 +713,7 @@ def replay_tuning_defaults() -> "Any":
         max_overhead=0.04, invest=0.0, budget_from="busy",
         charge_init=False, seq_buckets=True, idle_evict_s=None,
         pump_every=2, async_generation=True, prefetch=1,
-        kernel_tuning="kernel", cache_entries=4096)
+        cache_entries=4096)
 
 
 def replay_session(clock: Any, *, config: Any | None = None,

@@ -4,17 +4,12 @@
         [--autotune --requests 4 --registry /tmp/serve_tuned.json]
 
 All tuning knobs are the canonical ``repro.tune`` flag set, declared once
-by :meth:`repro.TuningConfig.add_flags` (strategy, kernel granularity and
-per-kernel strategies, budget caps, SLO gate, bucketing, async pipeline);
-the CLI builds one :class:`repro.TuningSession` and every request rides
-it, so later requests reuse the variants earlier ones discovered (and
-``--registry`` persists them across restarts).
-
-``--kernel-tuning`` selects the tuning granularity: ``program`` (whole
-step-programs), ``kernel`` (the model's matmul / attention / rmsnorm /
-decode_attention Pallas kernels tune as independent session-managed
-compilettes), ``both`` (hierarchical: step-programs plus their
-constituent kernels under one shared budget) or ``off``.
+by :meth:`repro.TuningConfig.add_flags` (strategy, budget caps, SLO
+gate, bucketing, async pipeline); the CLI builds one
+:class:`repro.TuningSession` and every request rides it, so later
+requests reuse the step-program variants earlier ones discovered (and
+``--registry`` persists them across restarts). ``--kernel-tuning off``
+turns tuning off even with ``--autotune``.
 """
 
 import argparse
@@ -90,7 +85,7 @@ def main() -> None:
     serve = ServeConfig(max_new_tokens=args.tokens, tuning=tcfg)
     # kernel_tuning="off" disables tuning even with --autotune: no
     # session, and generate() emits no "autotune" stats block
-    session = TuningSession(tcfg) if tcfg.active else None
+    session = TuningSession(tcfg) if tcfg.tune_program else None
 
     for req, out in enumerate(serve_requests(
             cfg, serve, session, batch=args.batch,
@@ -101,7 +96,7 @@ def main() -> None:
             a = out["autotune"]
             lc = a["lifecycle"]
             gc = a["generation_cache"]
-            line += (f"  [tuning({args.strategy}/{args.kernel_tuning}): "
+            line += (f"  [tuning({args.strategy}): "
                      f"{a['regenerations']} regens, {a['swaps']} swaps, "
                      f"overhead {a['overhead_frac']*100:.1f}%, "
                      f"gen stall {a['gen_stall_s']*1e3:.0f} ms, "
@@ -109,12 +104,6 @@ def main() -> None:
                      f"tuners {a['n_kernels']} "
                      f"({lc['converged']} converged, "
                      f"{lc['retired']} retired)]")
-            if args.kernel_tuning in ("kernel", "both"):
-                per = ", ".join(
-                    f"{name}:{k['strategy']}×{k['regenerations']}"
-                    for name, k in sorted(a["kernels"].items())
-                    if k.get("plane_managed"))
-                line += f"\n        kernels: {per}"
         print(line)
     if session is not None:
         session.close()

@@ -17,72 +17,10 @@ from repro.distributed.sharding import shard
 from repro.kernels.attention.ops import (
     decode_attention, flash_attention_jnp, prefill_attention)
 from repro.models.params import ParamDef
-from repro.runtime.kernel_plane import active_plane
-
-
-# ------------------------------------------------------------ kernel plane
-def _plane_routes(*arrays: jax.Array):
-    """The active kernel-tuning plane, when these EAGER arrays can route.
-
-    Inside a jit trace the arguments are tracers: the coordinator-managed
-    handle (a python-level function-pointer swap) cannot run there, so
-    traced call sites instead adopt the plane's best-known points (see
-    :func:`plane_attn_chunks`) and keep the pure-jnp kernel body.
-    """
-    plane = active_plane()
-    if plane is None:
-        return None
-    if any(isinstance(a, jax.core.Tracer) for a in arrays):
-        return None
-    return plane
-
-
-def plane_attn_chunks(cfg: ModelConfig) -> tuple[int, int]:
-    """Attention chunk sizes: the plane's tuned blocks, else cfg defaults.
-
-    This is the trace-time half of kernel-granular tuning: a jitted
-    step-program generated while a plane is active inherits the
-    attention kernel's independently tuned ``block_q``/``block_kv``
-    instead of the config's hard-coded chunk sizes (warm-started
-    registries make this bite from the very first trace of a restarted
-    process).
-    """
-    plane = active_plane()
-    if plane is not None and plane.adopt_points:
-        best = plane.best_point("attention")
-        if best is not None:
-            return (int(best.get("block_q", cfg.attn_q_chunk)),
-                    int(best.get("block_kv", cfg.attn_k_chunk)))
-    return cfg.attn_q_chunk, cfg.attn_k_chunk
-
-
-def plane_decode_chunk(cfg: ModelConfig) -> int:
-    """Flash-decoding KV chunk: the plane's tuned ``k_chunk``, else cfg's.
-
-    Trace-time adoption for the decode path: a jitted decode step traced
-    while a plane is active inherits the ``decode_attention`` kernel's
-    independently tuned chunk (per cache-length bucket) instead of the
-    hard-coded ``cfg.decode_k_chunk`` — suppressed, like the attention
-    chunks, when a program-level tuner owns the knob ("both" mode).
-    """
-    plane = active_plane()
-    if plane is not None and plane.adopt_points:
-        best = plane.best_point("decode_attention")
-        if best is not None:
-            return int(best.get("k_chunk", cfg.decode_k_chunk))
-    return cfg.decode_k_chunk
 
 
 # ----------------------------------------------------------------- norms
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
-    plane = _plane_routes(x, scale)
-    if plane is not None and eps == 1e-6 and x.ndim >= 2:
-        # coordinator-managed handle: the fused Pallas kernel tuned as an
-        # independent unit (block_rows its own space, own strategy)
-        shape = x.shape
-        y = plane.call("rmsnorm", x.reshape(-1, shape[-1]), scale)
-        if y is not None:
-            return y.reshape(shape)
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     return (x32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(x.dtype)
@@ -221,21 +159,12 @@ def self_attention(
         pos2d = positions if positions.ndim == 2 else positions[None]
         q = apply_rope(q, pos2d, cfg.rope_theta)
         k = apply_rope(k, pos2d, cfg.rope_theta)
-    plane = _plane_routes(q, k, v)
-    o = None
-    if (plane is not None and causal and q_offset == 0
-            and cfg.window is None):
-        # eager call with an active plane: the flash kernel runs as an
-        # independently tuned coordinator-managed unit
-        o = plane.call("attention", q, k, v)
-    if o is None:
-        qc, kc = plane_attn_chunks(cfg)
-        with jax.named_scope("attn.core"):
-            o = flash_attention_jnp(
-                q, k, v, causal=causal, q_offset=q_offset,
-                window=cfg.window, q_chunk=qc, k_chunk=kc,
-                scores_f32=cfg.attn_scores_f32,
-            )
+    with jax.named_scope("attn.core"):
+        o = flash_attention_jnp(
+            q, k, v, causal=causal, q_offset=q_offset,
+            window=cfg.window, q_chunk=cfg.attn_q_chunk,
+            k_chunk=cfg.attn_k_chunk, scores_f32=cfg.attn_scores_f32,
+        )
     return attn_out(o, p, cfg)
 
 
@@ -255,12 +184,13 @@ def self_attention_with_cache(
         pos2d = positions if positions.ndim == 2 else positions[None]
         q = apply_rope(q, pos2d, cfg.rope_theta)
         k = apply_rope(k, pos2d, cfg.rope_theta)
-    qc, kc = plane_attn_chunks(cfg)
     with jax.named_scope("attn.core"):
         # on a TPU the fused kernel: score tiles stay in VMEM and tiles
         # above the diagonal are skipped
-        o = prefill_attention(q, k, v, window=cfg.window, q_chunk=qc,
-                              k_chunk=kc, scores_f32=cfg.attn_scores_f32)
+        o = prefill_attention(q, k, v, window=cfg.window,
+                              q_chunk=cfg.attn_q_chunk,
+                              k_chunk=cfg.attn_k_chunk,
+                              scores_f32=cfg.attn_scores_f32)
     return attn_out(o, p, cfg), (k, v)
 
 
@@ -290,7 +220,9 @@ def _dus_bits(cache: jax.Array, update: jax.Array, start: tuple) -> jax.Array:
 
     XLA:CPU's float-normalization otherwise rewrites the bf16 DUS in f32,
     materializing an f32 copy of the whole cache in the dry-run memory
-    analysis. The bit view is exact and a no-op on TPU.
+    analysis. The bit view is exact but not free on TPU: the decode step
+    keeps a bitcast-convert fusion per cache under this scope (0.24 s
+    each in a traced slice of deepseek-7b decoding on a v5e).
     """
     if cache.dtype == jnp.bfloat16:
         c = jax.lax.bitcast_convert_type(cache, jnp.uint16)
@@ -335,17 +267,9 @@ def decode_self_attention(
     cache_k = shard(cache_k, "batch", "kv_seq", "kv", "kv_dh")
     cache_v = shard(cache_v, "batch", "kv_seq", "kv", "kv_dh")
     length = jnp.minimum(pos + 1, S_eff)
-    plane = _plane_routes(q, cache_k, cache_v)
-    o = None
-    if plane is not None:
-        # eager call with an active plane: flash-decoding runs as an
-        # independently tuned unit, keyed per cache-length bucket
-        o = plane.call("decode_attention", q, cache_k, cache_v,
-                       jnp.asarray(length, jnp.int32))
-    if o is None:
-        with jax.named_scope("attn.core"):
-            o = decode_attention(q, cache_k, cache_v, length=length,
-                                 k_chunk=plane_decode_chunk(cfg))
+    with jax.named_scope("attn.core"):
+        o = decode_attention(q, cache_k, cache_v, length=length,
+                             k_chunk=cfg.decode_k_chunk)
     return attn_out(o, p, cfg), (cache_k, cache_v)
 
 
@@ -360,8 +284,7 @@ def cross_attention(
     """Decoder cross-attention against precomputed encoder K/V."""
     q = jnp.einsum("btd,dhk->bthk", x, p["wq"].astype(x.dtype))
     if x.shape[1] == 1:
-        o = decode_attention(q, enc_k, enc_v,
-                             k_chunk=plane_decode_chunk(cfg))
+        o = decode_attention(q, enc_k, enc_v, k_chunk=cfg.decode_k_chunk)
     else:
         o = flash_attention_jnp(
             q, enc_k, enc_v, causal=False,

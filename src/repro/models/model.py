@@ -31,10 +31,11 @@ def model_kernel_specs(
 ) -> list[tuple[str, dict]]:
     """Constituent tunable kernels of a model's step-programs.
 
-    The hierarchical-registration shape list: for a (batch, seq) traffic
-    cell, the step-programs decompose into these catalog kernels, each
-    registered as an independent coordinator-managed compilette (its own
-    tuning space, strategy, registry key and cache lines). The paper's
+    The shape list ``TuningSession.attach_kernels`` registers: for a
+    (batch, seq) traffic cell, the step-programs decompose into these
+    catalog kernels, each registered as an independent
+    coordinator-managed compilette (its own tuning space, strategy,
+    registry key and cache lines). The paper's
     unit of analysis — the individual short-running kernel — keyed by
     the run-time constants the model bakes into it.
 

@@ -292,11 +292,10 @@ class TuningCoordinator:
         # keep counting what they spent/gained after they unregister.
         self._retired_accounts = TuningAccounts()
         self._n_retired = 0
-        # Busy time observed OUTSIDE managed tuners (observe_busy): a
-        # kernel-granular serve process runs its step-programs unmanaged,
-        # yet that is exactly the useful work a busy-time budget should
-        # accrue from — without it, per-kernel tuning would be starved
-        # forever (managed kernels are evaluated, never "called").
+        # Busy time observed OUTSIDE managed tuners (observe_busy): work
+        # no managed tuner counts is still useful work a busy-time budget
+        # should accrue from — without it, kernel handles that are only
+        # evaluated, never called, would be starved forever.
         self._external_busy_s = 0.0
         self._lock = threading.Lock()
         self._thread: threading.Thread | None = None
@@ -481,10 +480,10 @@ class TuningCoordinator:
     def observe_busy(self, seconds: float) -> None:
         """Credit useful work done outside any managed tuner.
 
-        Serving loops call this with the step-program time when the step
-        itself is NOT coordinator-managed (``kernel_tuning="kernel"``):
-        a ``budget_from="busy"`` policy then accrues budget from real
-        traffic exactly as it would had the step been a managed tuner.
+        A caller that runs work no managed tuner counts (the replay
+        harness's scripted host work) credits it here, so a
+        ``budget_from="busy"`` policy accrues budget from that traffic
+        exactly as it would had the work been a managed tuner's calls.
         Callers must not double-report work a ManagedTuner already
         counts (its calls accrue ``busy_s`` via calls × score).
         """

@@ -16,8 +16,8 @@ managed :class:`~repro.runtime.coordinator.ManagedTuner` —
     its own shapes, so pow2 bucketing cannot alias them the way it
     aliases chunk-clamping step-programs; registration sites bound
     shape diversity by pre-bucketing the extents they derive specs from
-    (serve uses ``lifecycle.bucket_length``) and idle eviction retires
-    the long tail;
+    (``TuningSession.attach_kernels`` uses ``lifecycle.bucket_length``)
+    and idle eviction retires the long tail;
   * **its own strategy** — ``strategies={"matmul": "greedy", ...}`` maps
     kernel names to search-strategy registry names (cf. "Tuning the
     Tuner": the best searcher is kernel-dependent), defaulting to the
@@ -26,11 +26,13 @@ managed :class:`~repro.runtime.coordinator.ManagedTuner` —
     the same :class:`~repro.core.RegenerationPolicy` budget as the
     step-program tuners, so adding per-kernel tuning never multiplies
     the overhead cap;
-  * **model integration** — :func:`use_kernel_plane` installs the plane
-    in a context variable; ``repro.models.layers`` routes eager kernel
-    calls through :meth:`KernelTuningPlane.call` and, inside jitted
-    step-program traces, adopts the plane's best-known kernel points
-    instead of hard-coded block sizes (:meth:`best_point`).
+  * **explicit handles** — the plane is a library: callers register a
+    kernel for a spec (:meth:`KernelTuningPlane.register_spec`, or
+    ``TuningSession.attach_kernels`` for a model's kernels) and route
+    calls through :meth:`KernelTuningPlane.call` themselves. The serve
+    and train loops call neither: their step programs are tuned whole,
+    and a served program reaches a catalog kernel only by lowering it
+    with the tiles the program tuner's point names.
 
 Pass ``virtual=(VirtualClock, DeviceProfile)`` to price every kernel by
 its analytical cost model instead of compiling — the deterministic
@@ -40,8 +42,6 @@ drive.
 
 from __future__ import annotations
 
-import contextlib
-import contextvars
 import json
 from typing import Any, Callable, Mapping
 
@@ -52,9 +52,7 @@ from repro.runtime.lifecycle import TunerState
 
 __all__ = [
     "KernelTuningPlane",
-    "active_plane",
     "parse_kernel_strategies",
-    "use_kernel_plane",
 ]
 
 
@@ -103,7 +101,6 @@ class KernelTuningPlane:
         gen_cost_s: "float | Callable[..., float] | None" = None,
         evaluator_factory: "Callable[[KernelCompilette], Any] | None" = None,
         eval_runs: int = 1,
-        adopt_points: bool = True,
         compilette_hook: "Callable[[KernelCompilette], None] | None" = None,
     ) -> None:
         self.coordinator = coordinator
@@ -118,11 +115,6 @@ class KernelTuningPlane:
         # generation: the fault-injection replay harness installs scripted
         # gate verdicts (``comp.gate_script``) and wrapped generators here.
         self.compilette_hook = compilette_hook
-        # Trace-time adoption: jitted step-programs read best_point() for
-        # their block sizes. Turned OFF when a program-level tuner owns
-        # those same parameters (serve/train "both" mode), so the two
-        # planes never fight over one knob.
-        self.adopt_points = adopt_points
         self._handles: dict[tuple[str, str], ManagedTuner] = {}
         # last concrete call arguments per handle: evaluations then
         # measure live traffic, falling back to synthetic example args.
@@ -145,18 +137,14 @@ class KernelTuningPlane:
         live-args table every time (re-building compilettes only for the
         coordinator's idempotent register to throw them away, and
         pinning evaluators to a dead plane's live-args). Construction
-        kwargs apply on first use; the *mutable* config knobs
-        (``adopt_points``, ``strategies``) are re-applied on every call,
-        so a request that switches tuning mode (kernel ↔ both) cannot
-        leave a stale plane fighting a program tuner over one knob.
+        kwargs apply on first use; ``strategies`` (and a
+        ``compilette_hook``) are re-applied on every call.
         """
         plane = getattr(coordinator, "_kernel_plane", None)
         if plane is None:
             plane = cls(coordinator, **kwargs)
             coordinator._kernel_plane = plane
         else:
-            if "adopt_points" in kwargs:
-                plane.adopt_points = kwargs["adopt_points"]
             if kwargs.get("strategies"):
                 plane.strategies.update(kwargs["strategies"])
             if kwargs.get("compilette_hook") is not None:
@@ -193,7 +181,7 @@ class KernelTuningPlane:
         A spec at which every tuning point is a hole (e.g. a reduced
         model whose K is below the smallest block_k) is untunable:
         ``require=True`` raises, ``require=False`` returns ``None`` (the
-        serve/train hierarchical registration skips such kernels).
+        ``TuningSession.attach_kernels`` skips such kernels).
         """
         self.prune_released()
         bucketed = self.coordinator.lifecycle.bucket_specialization(
@@ -297,29 +285,6 @@ class KernelTuningPlane:
                if name is None or n == name]
         return [m for m in out if m.state is not TunerState.RETIRED]
 
-    def best_point(self, name: str,
-                   spec: Mapping[str, Any] | None = None) -> dict | None:
-        """Best-known tuned point for ``name`` (for trace-time adoption).
-
-        With ``spec``, the exact bucketed handle is consulted; otherwise
-        the most-called handle of that kernel (the shape that dominates
-        live traffic) answers. ``None`` until something was measured.
-        """
-        if spec is not None:
-            bucketed = self.coordinator.lifecycle.bucket_specialization(
-                dict(spec))
-            m = self._handles.get((name, _canon(bucketed)))
-            candidates = [m] if m is not None else []
-        else:
-            candidates = sorted(
-                self.handles(name),
-                key=lambda m: -m.tuner.accounts.kernel_calls)
-        for m in candidates:
-            best = m.tuner.explorer.best_point
-            if best is not None:
-                return dict(best)
-        return None
-
     def stats(self) -> dict[str, Any]:
         return {
             "handles": {
@@ -327,23 +292,3 @@ class KernelTuningPlane:
                 for (n, spec), m in self._handles.items()
             },
         }
-
-
-# ----------------------------------------------------------- active plane
-_ACTIVE: "contextvars.ContextVar[KernelTuningPlane | None]" = (
-    contextvars.ContextVar("kernel_tuning_plane", default=None))
-
-
-def active_plane() -> KernelTuningPlane | None:
-    """The plane installed by :func:`use_kernel_plane`, if any."""
-    return _ACTIVE.get()
-
-
-@contextlib.contextmanager
-def use_kernel_plane(plane: KernelTuningPlane | None):
-    """Install ``plane`` for model code (layers) to route kernels through."""
-    token = _ACTIVE.set(plane)
-    try:
-        yield plane
-    finally:
-        _ACTIVE.reset(token)

@@ -22,23 +22,16 @@ the coordinator machinery. The serving regime it runs under:
     eviction or a restart warm-start never recompile) while the live
     step-programs keep serving — the paper's double-buffered code
     generation, serving-grade;
-  * **hierarchical registration** (``kernel_tuning``): beside the whole
-    step-programs, ``session.attach_kernels`` registers the model's
-    constituent Pallas kernels (matmul, attention, rmsnorm, and the
-    decode path's flash-decoding ``decode_attention`` keyed per
-    cache-length bucket) as independent compilettes — each with its own
-    tuning space, search strategy, registry warm-start key and
-    generation-cache lines, all drawing slots from the same shared
-    budget. ``"program"`` is the pre-PR-4 behaviour, ``"kernel"`` tunes
-    only the kernels (step-programs adopt the kernels' best block sizes
-    at trace time), ``"both"`` runs the two levels together (program
-    points own the step-level knobs).
+  * **one tuning level**: the tuners are the two step programs,
+    ``serve_prefill`` (attention tiles ``attn_q_chunk``/``attn_k_chunk``)
+    and ``serve_decode`` (``decode_k_chunk``). A point is a
+    ``ModelConfig`` the program is built from, so the tiles of a kernel
+    lowered inside the program (the prefill's fused attention on a TPU)
+    are the point's.
 
 Pass a long-lived session (one per serving process) so tuning state,
 budget and warm-started best points persist across requests; within a
 single ``generate`` call tuning already begins between decode steps.
-``make_serve_coordinator`` and the bare ``coordinator=`` argument remain
-as deprecated shims over the session.
 """
 
 from __future__ import annotations
@@ -47,20 +40,12 @@ import contextlib
 import dataclasses
 import functools
 import time
-import warnings
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from repro.api import (
-    KERNEL_TUNING_MODES,
-    TuningConfig,
-    TuningSession,
-    apply_tuning_kwargs,
-    install_tuning_aliases,
-    serve_tuning_defaults,
-)
+from repro.api import TuningConfig, TuningSession, serve_tuning_defaults
 from repro.configs.base import ModelConfig
 from repro.core import (
     Compilette,
@@ -71,46 +56,16 @@ from repro.core import (
     telemetry,
 )
 from repro.kernels.attention.ops import prefill_kernel_tiles
-from repro.models.layers import plane_attn_chunks
 from repro.models.model import build_model
 
 __all__ = [
-    "KERNEL_TUNING_MODES",
     "ServeConfig",
     "generate",
-    "make_serve_coordinator",
     "serve_tuning_defaults",   # re-export: the regime base lives in api
 ]
 
-# legacy ServeConfig field → TuningConfig field
-_TUNING_ALIASES = {
-    "autotune": "enabled",
-    "tune_max_overhead": "max_overhead",
-    "tune_invest": "invest",
-    "tune_strategy": "strategy",
-    "tune_slo_s": "slo_s",
-    "tune_slo_quantile": "slo_quantile",
-    "seq_buckets": "seq_buckets",
-    "idle_evict_s": "idle_evict_s",
-    "registry_path": "registry_path",
-    "pump_every": "pump_every",
-    "async_generation": "async_generation",
-    "prefetch": "prefetch",
-    "compile_workers": "compile_workers",
-    "compile_backend": "compile_backend",
-    "kernel_tuning": "kernel_tuning",
-    "kernel_strategies": "strategies",
-}
-
-
 class ServeConfig:
-    """Serving knobs; tuning knobs live in the embedded ``tuning`` config.
-
-    The legacy flat fields (``autotune``, ``tune_strategy``,
-    ``kernel_strategies``, …) remain accepted as constructor keywords
-    and readable/writable properties, aliasing into ``self.tuning`` —
-    pre-PR-5 call sites keep working unchanged.
-    """
+    """Serving knobs; tuning knobs live in the embedded ``tuning`` config."""
 
     def __init__(
         self,
@@ -119,7 +74,6 @@ class ServeConfig:
         temperature: float = 1.0,
         seed: int = 0,
         tuning: TuningConfig | None = None,
-        **legacy: Any,
     ) -> None:
         self.max_new_tokens = max_new_tokens
         self.greedy = greedy
@@ -127,16 +81,11 @@ class ServeConfig:
         self.seed = seed
         self.tuning = tuning if tuning is not None else \
             serve_tuning_defaults()
-        apply_tuning_kwargs(self.tuning, _TUNING_ALIASES, legacy,
-                            "ServeConfig")
 
     def __repr__(self) -> str:  # cache_token-stable (identity-free)
         return (f"ServeConfig(max_new_tokens={self.max_new_tokens}, "
                 f"greedy={self.greedy}, temperature={self.temperature}, "
                 f"seed={self.seed}, tuning={self.tuning})")
-
-
-install_tuning_aliases(ServeConfig, _TUNING_ALIASES)
 
 
 def _prefill_compilette(model_cfg: ModelConfig, seq: int) -> Compilette:
@@ -212,23 +161,8 @@ def _prefill_tiles(model_cfg: ModelConfig, prefill: Any, params: Any,
     if point:
         blocks = point["attn_q_chunk"], point["attn_k_chunk"]
     else:
-        blocks = plane_attn_chunks(model_cfg)
+        blocks = model_cfg.attn_q_chunk, model_cfg.attn_k_chunk
     return prefill_kernel_tiles(model_cfg, _platform(params), B, T, blocks)
-
-
-def make_serve_coordinator(serve: ServeConfig, *, clock=None):
-    """Deprecated: build the serving session's coordinator directly.
-
-    Thin shim over :class:`repro.api.TuningSession` — the session API is
-    the one front door; this remains so pre-PR-5 call sites (and their
-    tests) keep working. Returns the coordinator of a fresh session; the
-    session is recoverable via ``TuningSession.adopt``.
-    """
-    warnings.warn(
-        "make_serve_coordinator is deprecated: construct a "
-        "repro.TuningSession(serve.tuning) and pass session=... to "
-        "generate()", DeprecationWarning, stacklevel=2)
-    return TuningSession(serve.tuning, clock=clock).coordinator
 
 
 @telemetry.traced("serve.request")
@@ -236,15 +170,13 @@ def generate(
     model_cfg: ModelConfig,
     batch: dict[str, Any],
     serve: ServeConfig | None = None,
-    coordinator: Any | None = None,
     session: TuningSession | None = None,
 ) -> dict[str, Any]:
     """Prefill the prompt batch, then decode ``max_new_tokens`` greedily.
 
-    Tuning state lives in ``session`` (one per serving process). The
-    legacy ``coordinator=`` argument is adopted into its session; with
-    neither, an ephemeral session is built from ``serve.tuning`` and
-    closed when the request finishes.
+    Tuning state lives in ``session`` (one per serving process); without
+    one, an ephemeral session is built from ``serve.tuning`` and closed
+    when the request finishes.
 
     Returns the tokens, ``first_token_s`` (entry until the prefill's
     logits are ready), ``prefill_s`` (prefill and cache widening),
@@ -252,21 +184,11 @@ def generate(
     """
     t_entry = time.perf_counter()
     serve = serve or ServeConfig()
-    tcfg = serve.tuning
-    if tcfg.kernel_tuning not in KERNEL_TUNING_MODES:
-        raise ValueError(
-            f"kernel_tuning must be one of {KERNEL_TUNING_MODES}, "
-            f"got {tcfg.kernel_tuning!r}")
-    tune_program = tcfg.tune_program
-    tune_kernels = tcfg.tune_kernels
-    tuning = tune_program or tune_kernels
+    tuning = serve.tuning.tune_program
     own_session = False
     if tuning and session is None:
-        if coordinator is not None:
-            session = TuningSession.adopt(coordinator, tcfg)
-        else:
-            session = TuningSession(tcfg)
-            own_session = True
+        session = TuningSession(serve.tuning)
+        own_session = True
     decode_state: dict[str, Any] = {}
     with telemetry.span("serve.setup"):
         model = build_model(model_cfg)
@@ -285,17 +207,7 @@ def generate(
         prefill = jax.jit(model.prefill)
         decode = _decode_program(model_cfg)
 
-        # ---- online tuning: step-programs + constituent kernels ---------
-        if tune_kernels:
-            # Hierarchical registration, kernel level: the model's
-            # constituent Pallas kernels become independent
-            # session-managed compilettes (own space/strategy/registry
-            # key), drawing regeneration slots from the same shared
-            # budget as the step-programs. Untunable shapes (every point
-            # a hole at a reduced size) are skipped, not fatal.
-            session.attach_kernels(model_cfg, batch=B, seq=T,
-                                   max_len=max_len)
-        if tune_program:
+        if tuning:
             # The compilette's chunk options are bounded by the BUCKETED
             # extent, matching the bucketed specialization key the
             # session registers under — so seq 120 and 150 build the
@@ -315,17 +227,14 @@ def generate(
             # so measurements stay representative of live traffic.
             prefill.tuner.evaluator.make_args = prefill_ev.make_args
 
-    # The session scope stays active for the whole request: jitted
-    # step-programs traced in here adopt tuned kernel block sizes, and
-    # any eager kernel call routes through its managed handle.
     scope_ctx = session.scope() if session is not None \
         else contextlib.nullcontext()
     try:
         with scope_ctx:
             return _generate_inner(
                 model_cfg, model, params, batch, serve, session,
-                prefill, decode, B, T, max_len, tuning, tune_program,
-                decode_state, t_entry)
+                prefill, decode, B, T, max_len, tuning, decode_state,
+                t_entry)
     finally:
         if own_session:
             session.close()
@@ -333,22 +242,13 @@ def generate(
 
 def _generate_inner(
     model_cfg, model, params, batch, serve, session,
-    prefill, decode, B, T, max_len, tuning, tune_program,
-    decode_state, t_entry,
+    prefill, decode, B, T, max_len, tuning, decode_state, t_entry,
 ) -> dict[str, Any]:
-    # Busy-time credit for unmanaged step-programs: with kernel-only
-    # tuning the prefill/decode calls are real traffic a busy-time
-    # budget must accrue from, but no ManagedTuner counts them (a
-    # managed step reports its own calls — never double-credit).
-    credit_busy = tuning and not tune_program
-
     t0 = time.perf_counter()
     with telemetry.span("serve.prefill"):
         logits, cache = prefill(params, batch)
         jax.block_until_ready(logits)
     t_first = time.perf_counter()
-    if credit_busy:
-        session.observe_busy(t_first - t0)
     with telemetry.span("serve.cache_widen"):
         # widen KV caches to max_len where the family uses positional
         # caches
@@ -376,7 +276,7 @@ def _generate_inner(
     telemetry.counter("attn.prefill_tiles_run", tiles_run)
 
     with telemetry.span("serve.setup"):
-        if tune_program:
+        if tuning:
             # The decode evaluator replays the *current* decoding state
             # on a copy of its cache, one per call (the step consumes the
             # cache it is given); its outputs are discarded, so
@@ -404,7 +304,6 @@ def _generate_inner(
     with telemetry.span("serve.decode"):
         for i in range(serve.max_new_tokens - 1):
             with telemetry.span("serve.decode_step"):
-                t_step = time.perf_counter()
                 logits, cache, *counts = decode(params, cache, tokens,
                                                 jnp.int32(pos0 + i))
                 if counts:
@@ -414,20 +313,9 @@ def _generate_inner(
                 tokens = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(
                     jnp.int32)
                 out_tokens.append(tokens)
-                if credit_busy:
-                    # sync before crediting: jax dispatch is asynchronous,
-                    # so without it the credited interval would be the
-                    # enqueue time (µs) while the device executes inside
-                    # the final block_until_ready — and a busy-time
-                    # budget would starve exactly the kernel tuning this
-                    # credit exists to fund
-                    jax.block_until_ready(tokens)
-                    session.observe_busy(time.perf_counter() - t_step)
             if tuning:
-                if tune_program:
-                    decode_state.update(
-                        cache=cache, tokens=tokens,
-                        pos=jnp.int32(pos0 + i + 1))
+                decode_state.update(cache=cache, tokens=tokens,
+                                    pos=jnp.int32(pos0 + i + 1))
                 session.maybe_pump()
         jax.block_until_ready(tokens)
     t_decode = time.perf_counter() - t1
@@ -457,6 +345,5 @@ def _generate_inner(
             # cache, and tuners idle past the eviction horizon are
             # unregistered.
             session.sweep()
-            out["kernel_tuning"] = serve.tuning.kernel_tuning
             out["autotune"] = session.stats()
         return out

@@ -10,8 +10,7 @@ reports, exactly like the paper's "all run-time overheads included".
 Tuning is configured by the embedded :class:`~repro.api.TuningConfig`
 (``TrainLoopConfig.tuning``) and owned by a
 :class:`~repro.api.TuningSession`: the budget is shared with any other
-tunable step-programs (and, in kernel modes, the model's constituent
-catalog kernels), and the best points are persisted next to the
+tunable step-programs, and the best points are persisted next to the
 checkpoints so a restarted (or elastically re-scaled) job warm-starts
 instead of re-exploring.
 
@@ -36,14 +35,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.api import (
-    KERNEL_TUNING_MODES,
-    TuningConfig,
-    TuningSession,
-    apply_tuning_kwargs,
-    install_tuning_aliases,
-    train_tuning_defaults,
-)
+from repro.api import TuningConfig, TuningSession, train_tuning_defaults
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.core import (
@@ -55,28 +47,8 @@ from repro.models.model import build_model
 from repro.models.params import init_tree
 from repro.optim.adamw import AdamW, OptimizerConfig
 
-# legacy TrainLoopConfig field → TuningConfig field
-_TUNING_ALIASES = {
-    "autotune": "enabled",
-    "tune_max_overhead": "max_overhead",
-    "tune_invest": "invest",
-    "tune_strategy": "strategy",
-    "tune_async": "async_generation",
-    "tune_prefetch": "prefetch",
-    "compile_workers": "compile_workers",
-    "compile_backend": "compile_backend",
-    "kernel_tuning": "kernel_tuning",
-    "kernel_strategies": "strategies",
-}
-
-
 class TrainLoopConfig:
-    """Loop knobs; tuning knobs live in the embedded ``tuning`` config.
-
-    The legacy flat fields (``autotune``, ``tune_strategy``,
-    ``tune_async``, …) remain accepted as constructor keywords and
-    readable/writable properties, aliasing into ``self.tuning``.
-    """
+    """Loop knobs; tuning knobs live in the embedded ``tuning`` config."""
 
     def __init__(
         self,
@@ -90,7 +62,6 @@ class TrainLoopConfig:
         fail_at_step: int | None = None,
         log_every: int = 10,
         tuning: TuningConfig | None = None,
-        **legacy: Any,
     ) -> None:
         self.steps = steps
         self.ckpt_every = ckpt_every
@@ -103,11 +74,6 @@ class TrainLoopConfig:
         self.log_every = log_every
         self.tuning = tuning if tuning is not None else \
             train_tuning_defaults()
-        apply_tuning_kwargs(self.tuning, _TUNING_ALIASES, legacy,
-                            "TrainLoopConfig")
-
-
-install_tuning_aliases(TrainLoopConfig, _TUNING_ALIASES)
 
 
 class FaultInjected(RuntimeError):
@@ -162,10 +128,6 @@ def train(
 ) -> dict[str, Any]:
     loop = loop or TrainLoopConfig()
     tcfg = loop.tuning
-    if tcfg.kernel_tuning not in KERNEL_TUNING_MODES:
-        raise ValueError(
-            f"kernel_tuning must be off|program|kernel|both, "
-            f"got {tcfg.kernel_tuning!r}")
     model = build_model(model_cfg)
     optimizer = AdamW(opt_cfg or OptimizerConfig(warmup_steps=10,
                                                  total_steps=loop.steps))
@@ -196,26 +158,17 @@ def train(
 
     session = None
     tuner = None
-    tune_program = tcfg.tune_program
-    tune_kernels = tcfg.tune_kernels
-    if tune_program or tune_kernels:
+    if tcfg.tune_program:
         # One session per training process: a single regeneration budget
-        # shared by every tunable step-program AND every constituent
-        # kernel, warm-started from the checkpoint-adjacent registry so
-        # a restarted job skips re-exploration. Variant jitting overlaps
-        # the training steps; a resumed job whose registry warm-start
-        # re-proposes known points hits the generation cache instead of
-        # re-building the step program.
+        # shared by every tunable step-program, warm-started from the
+        # checkpoint-adjacent registry so a restarted job skips
+        # re-exploration. Variant jitting overlaps the training steps; a
+        # resumed job whose registry warm-start re-proposes known points
+        # hits the generation cache instead of re-building the step
+        # program.
         if tcfg.registry_path is None:
             tcfg = dataclasses.replace(tcfg, registry_path=registry_path)
         session = TuningSession(tcfg)
-    if tune_kernels:
-        # Hierarchical registration, kernel level: each Pallas kernel of
-        # the step-program tunes as an independent compilette under the
-        # shared budget (untunable reduced shapes are skipped).
-        B_k, T_k = first_batch["tokens"].shape
-        session.attach_kernels(model_cfg, batch=B_k, seq=T_k)
-    if tune_program:
         comp = _attention_step_compilette(
             model_cfg, model, optimizer, ef, first_batch, shape.seq_len)
         spec = {"seq": shape.seq_len}

@@ -1,17 +1,15 @@
-"""PR-5 front door: repro.tune / repro.tuned / repro.TuningSession.
+"""The front door: repro.tune / repro.tuned / repro.TuningSession.
 
 Round-trip suite for the session API: @tuned convergence on the
 VirtualClock (no sleeps), config parity across programmatic / env /
 flags construction, stats parity between the session path and the
-equivalent PR-4 coordinator wiring, the close()/scope() re-entrancy
-regression, the decode_attention plane kernel, the generation-cache
-byte bound, and the deprecated-constructor import lint.
+equivalent explicit coordinator wiring, the close()/scope() re-entrancy
+regression, the decode_attention plane kernel and the generation-cache
+byte bound.
 """
 
 import argparse
-import importlib.util
 import os
-import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +35,6 @@ from repro.core import (
 )
 from repro.kernels import get_catalog
 from repro.runtime.coordinator import TuningCoordinator
-from repro.runtime.kernel_plane import active_plane
 from repro.runtime.lifecycle import TunerState
 
 GEN_COST = 0.002
@@ -199,7 +196,7 @@ def test_config_from_env_flags_programmatic_identical():
         "REPRO_TUNE_STRATEGY": "greedy",
         "REPRO_TUNE_MAX_OVERHEAD": "0.5",
         "REPRO_TUNE_INVEST": "0.25",
-        "REPRO_TUNE_KERNEL_TUNING": "both",
+        "REPRO_TUNE_KERNEL_TUNING": "off",
         "REPRO_TUNE_STRATEGIES": "matmul=greedy,attention=random",
         "REPRO_TUNE_REGISTRY_PATH": "/tmp/api_r.json",
         "REPRO_TUNE_SLO_S": "0.05",
@@ -214,7 +211,7 @@ def test_config_from_env_flags_programmatic_identical():
     TuningConfig.add_flags(parser, base=base)
     args = parser.parse_args([
         "--autotune", "--strategy", "greedy", "--tune-overhead", "0.5",
-        "--tune-invest", "0.25", "--kernel-tuning", "both",
+        "--tune-invest", "0.25", "--kernel-tuning", "off",
         "--kernel-strategy", "matmul=greedy",
         "--kernel-strategy", "attention=random",
         "--registry", "/tmp/api_r.json", "--slo", "0.05",
@@ -228,7 +225,7 @@ def test_config_from_env_flags_programmatic_identical():
         strategies={"matmul": "greedy", "attention": "random"},
         max_overhead=0.5, invest=0.25, registry_path="/tmp/api_r.json",
         slo_s=0.05, slo_quantile=0.99, seq_buckets=False,
-        async_generation=False, prefetch=3, kernel_tuning="both")
+        async_generation=False, prefetch=3, kernel_tuning="off")
     assert cfg_env == cfg_flags == cfg_prog
     # the session classmethods accept the same inputs
     s = TuningSession.from_env(env, base=base, clock=VirtualClock())
@@ -262,8 +259,6 @@ def test_from_env_bad_strategies_raise_value_error():
 
 
 def test_config_validation_fails_fast():
-    with pytest.raises(ValueError, match="kernel_tuning"):
-        TuningConfig(kernel_tuning="bogus")
     with pytest.raises(ValueError, match="budget_from"):
         TuningConfig(budget_from="idle")
     with pytest.raises(ValueError, match="REPRO_TUNE_TYPO"):
@@ -273,6 +268,22 @@ def test_config_validation_fails_fast():
     args = parser.parse_args(["--slo-quantile", "0.99"])
     with pytest.raises(SystemExit):   # quantile gate needs an SLO
         TuningConfig.from_flags(args)
+
+
+@pytest.mark.parametrize("mode", ["kernel", "both", "bogus"])
+def test_config_refuses_retired_kernel_tuning_modes(mode):
+    """One tuning level: the step programs. The retired kernel-level modes
+    are refused like any unknown mode, programmatically, from the
+    environment and on the command line."""
+    assert repro.KERNEL_TUNING_MODES == ("off", "program")
+    with pytest.raises(ValueError, match="kernel_tuning must be one of"):
+        TuningConfig(kernel_tuning=mode)
+    with pytest.raises(ValueError, match="kernel_tuning must be one of"):
+        TuningConfig.from_env({"REPRO_TUNE_KERNEL_TUNING": mode})
+    parser = argparse.ArgumentParser()
+    TuningConfig.add_flags(parser)
+    with pytest.raises(SystemExit):
+        parser.parse_args(["--kernel-tuning", mode])
 
 
 def test_config_refuses_process_compile_backend():
@@ -294,7 +305,7 @@ def test_config_refuses_process_compile_backend():
 _KNOB_ASSIGNMENTS = st.tuples(
     st.booleans(),                                          # enabled
     st.sampled_from(["two_phase", "random", "greedy"]),     # strategy
-    st.sampled_from(["off", "program", "kernel", "both"]),  # kernel_tuning
+    st.sampled_from(["off", "program"]),                    # kernel_tuning
     st.dictionaries(                                        # strategies
         st.sampled_from(["matmul", "attention", "rmsnorm"]),
         st.sampled_from(["two_phase", "random", "greedy"]),
@@ -440,79 +451,6 @@ def test_session_close_flushes_registry(tmp_path):
         {"unroll": 8}
 
 
-# ------------------------------------------------------- deprecation shims
-def test_legacy_config_fields_alias_into_tuning():
-    from repro.runtime.serve_loop import ServeConfig
-    from repro.runtime.train_loop import TrainLoopConfig
-
-    serve = ServeConfig(autotune=True, tune_strategy="greedy",
-                        kernel_strategies={"matmul": "greedy"},
-                        tune_max_overhead=0.3)
-    assert serve.tuning.enabled and serve.autotune
-    assert serve.tuning.strategy == "greedy" == serve.tune_strategy
-    assert serve.tuning.strategies == {"matmul": "greedy"}
-    assert serve.tuning.max_overhead == 0.3
-    serve.tune_slo_s = 0.05            # property writes reach the config
-    assert serve.tuning.slo_s == 0.05
-    # serving-grade defaults survive the collapse
-    assert serve.tuning.budget_from == "busy" and serve.tuning.charge_init
-    with pytest.raises(TypeError, match="unexpected"):
-        ServeConfig(bogus_knob=1)
-
-    loop = TrainLoopConfig(autotune=True, tune_async=False,
-                           tune_prefetch=2)
-    assert loop.tuning.enabled
-    assert loop.tuning.async_generation is False
-    assert loop.tuning.prefetch == 2 == loop.tune_prefetch
-    assert loop.tuning.budget_from == "wall"
-    assert loop.tuning.seq_buckets is False   # train-grade defaults
-    with pytest.raises(TypeError, match="unexpected"):
-        TrainLoopConfig(bogus_knob=1)
-
-
-def test_make_serve_coordinator_shim_warns_and_matches_session_path():
-    """The deprecated constructor warns, and a request through it rolls
-    up stats identically in structure to the session front door."""
-    from repro.runtime.serve_loop import (
-        ServeConfig, generate, make_serve_coordinator)
-
-    cfg = REGISTRY["deepseek-7b"].reduced()
-    serve = ServeConfig(max_new_tokens=4, autotune=True,
-                        tune_max_overhead=0.5, kernel_tuning="both",
-                        kernel_strategies={"attention": "greedy"},
-                        seq_buckets=True, idle_evict_s=None)
-    with pytest.warns(DeprecationWarning, match="TuningSession"):
-        coordinator = make_serve_coordinator(serve)
-    # the shim's coordinator is itself session-owned (one front door)
-    assert isinstance(getattr(coordinator, "_session", None), TuningSession)
-
-    def batch():
-        return {"tokens": jnp.ones((2, 24), jnp.int32)}
-
-    out_shim = generate(cfg, batch(), serve, coordinator=coordinator)
-    session = TuningSession(serve.tuning)
-    try:
-        out_sess = generate(cfg, batch(), serve, session=session)
-        for out in (out_shim, out_sess):
-            a = out["autotune"]
-            # identical rollup arithmetic: per-kernel sums + tombstone
-            # reconcile exactly with the aggregate on both paths
-            for f in ("gen_spent_s", "gen_stall_s", "eval_spent_s"):
-                rollup = (sum(k[f] for k in a["kernels"].values())
-                          + a["retired_accounts"][f])
-                assert rollup == pytest.approx(a[f]), f
-        a, b = out_shim["autotune"], out_sess["autotune"]
-        assert set(a["kernels"]) == set(b["kernels"])
-        for name in a["kernels"]:
-            assert (a["kernels"][name]["strategy"]
-                    == b["kernels"][name]["strategy"]), name
-        # hierarchical registration includes the PR-5 decode kernel
-        assert "decode_attention" in a["kernels"]
-    finally:
-        session.close()
-        TuningSession.adopt(coordinator).close()
-
-
 # ------------------------------------------------------- decode_attention
 def test_decode_attention_kernel_matches_oracle():
     """Real backend: any k_chunk variant computes the same attention as
@@ -543,9 +481,7 @@ def test_decode_attention_kernel_matches_oracle():
 def test_decode_attention_tunes_per_cache_length_bucket():
     """Satellite acceptance: attach_kernels registers the decode kernel
     keyed per cache-length bucket; each bucket converges to its own
-    cost-model optimum and the decode path adopts it at trace time."""
-    from repro.models.layers import plane_decode_chunk
-
+    cost-model optimum."""
     model_cfg = REGISTRY["deepseek-7b"].reduced()
     clock = VirtualClock()
     cfg = TuningConfig(max_overhead=1.0, invest=0.5, pump_every=1)
@@ -570,17 +506,6 @@ def test_decode_attention_tunes_per_cache_length_bucket():
         comp.space.iter_valid(),
         key=lambda p: comp.simulate(p, TPU_V5E))
     assert h.tuner.explorer.best_point == expected
-    # trace-time adoption: inside the session scope the decode path reads
-    # the tuned chunk; outside (or with a program tuner owning the knob)
-    # the config default stands
-    assert plane_decode_chunk(model_cfg) == model_cfg.decode_k_chunk
-    with session.scope():
-        assert active_plane() is plane
-        assert plane_decode_chunk(model_cfg) == expected["k_chunk"]
-    plane.adopt_points = False
-    with session.scope():
-        assert plane_decode_chunk(model_cfg) == model_cfg.decode_k_chunk
-    plane.adopt_points = True
     # a second cache-length cell gets its own handle (own bucket key)
     session.attach_kernels(model_cfg, batch=2, seq=24, max_len=1000)
     assert len(plane.handles("decode_attention")) == 2
@@ -744,18 +669,6 @@ def test_aot_compile_records_size_estimate():
     assert "size_bytes" in kern.meta
     size = kern.meta["size_bytes"]
     assert size is None or size > 0
-
-
-# ------------------------------------------------------------------- lint
-def test_no_deprecated_constructor_imports():
-    """CI satellite, enforced in tier-1 too: src/repro/runtime and
-    src/repro/launch must not import the deprecated constructors."""
-    tool = (pathlib.Path(__file__).resolve().parent.parent
-            / "tools" / "check_deprecated_imports.py")
-    spec = importlib.util.spec_from_file_location("check_deprecated", tool)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.violations() == []
 
 
 # ------------------------------------------------------------ plane prune

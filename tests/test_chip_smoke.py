@@ -28,6 +28,14 @@ def test_smoke_path_serves_reduced_model_on_cpu():
     assert len(report["outs"]) == 2
     assert all(out["logits_finite"] for out in report["outs"])
     assert report["param_bytes"] > 0
+    # serving tuned the step programs; the catalog kernels were attached
+    # afterwards and each handle called once
+    kernels = report["stats"]["kernels"]
+    assert any(k.startswith("serve_prefill") for k in kernels)
+    assert any(k.startswith("serve_decode") for k in kernels)
+    plane = {k: v for k, v in kernels.items() if v.get("plane_managed")}
+    assert len(plane) == 4
+    assert all(v["kernel_calls"] == 1 for v in plane.values()), plane
     # every catalog kernel of the serve path attached, compiled ahead of
     # time, and its base point passed the gate against its ref.py
     assert sorted(name for name, *_ in report["base_gate"]) == [

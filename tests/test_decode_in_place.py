@@ -115,7 +115,7 @@ def test_decode_evaluations_between_steps_keep_the_served_tokens(monkeypatch):
     cache. Nothing reads a donated buffer, and the tokens are those of a
     run with tuning off (the variants' cache chunks divide no served
     cache length here, so they all compute the same)."""
-    from repro.api import TuningSession
+    from repro.api import TuningSession, serve_tuning_defaults
     from repro.runtime.serve_loop import ServeConfig, generate
 
     cfg = _bf16_dense()
@@ -123,10 +123,11 @@ def test_decode_evaluations_between_steps_keep_the_served_tokens(monkeypatch):
     tokens = jax.random.randint(jax.random.PRNGKey(3), (1, T), 0, cfg.vocab)
     plain = generate(cfg, {"tokens": tokens},
                      ServeConfig(max_new_tokens=new, seed=1))
-    serve = ServeConfig(max_new_tokens=new, seed=1, autotune=True,
-                        tune_max_overhead=1e6, pump_every=1,
-                        async_generation=False, kernel_tuning="program",
-                        idle_evict_s=None)
+    serve = ServeConfig(max_new_tokens=new, seed=1,
+                        tuning=dataclasses.replace(
+                            serve_tuning_defaults(), enabled=True,
+                            max_overhead=1e6, pump_every=1,
+                            async_generation=False, idle_evict_s=None))
     session = TuningSession(serve.tuning)
     timed = []
     evaluate = Evaluator.evaluate

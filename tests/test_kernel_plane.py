@@ -27,9 +27,7 @@ from repro.kernels import KernelCompilette, KernelDef, get_catalog
 from repro.runtime.coordinator import TuningCoordinator
 from repro.runtime.kernel_plane import (
     KernelTuningPlane,
-    active_plane,
     parse_kernel_strategies,
-    use_kernel_plane,
 )
 from repro.runtime.lifecycle import TunerLifecycle, TunerState
 
@@ -361,18 +359,15 @@ def test_shared_plane_is_one_per_coordinator():
 
 
 def test_shared_plane_reapplies_mutable_config():
-    """A request that switches tuning mode must not inherit a stale
-    adopt_points/strategies from the memoized plane."""
+    """A later attach must not run on stale strategies of the memoized
+    plane: each call's strategies are merged into it."""
     clock = VirtualClock()
     coord = TuningCoordinator(policy=RegenerationPolicy(1.0, 0.5),
                               device="test:v", clock=clock)
-    p = KernelTuningPlane.shared(coord, adopt_points=True,
-                                 strategies={"matmul": "greedy"})
-    assert p.adopt_points and p.strategies == {"matmul": "greedy"}
-    p2 = KernelTuningPlane.shared(coord, adopt_points=False,
-                                  strategies={"rmsnorm": "random"})
+    p = KernelTuningPlane.shared(coord, strategies={"matmul": "greedy"})
+    assert p.strategies == {"matmul": "greedy"}
+    p2 = KernelTuningPlane.shared(coord, strategies={"rmsnorm": "random"})
     assert p2 is p
-    assert p.adopt_points is False
     assert p.strategies == {"matmul": "greedy", "rmsnorm": "random"}
 
 
@@ -418,67 +413,6 @@ def test_parse_kernel_strategies_validates_both_sides():
         parse_kernel_strategies(["matmul=simulated_annealing"])
     with pytest.raises(SystemExit):          # missing '='
         parse_kernel_strategies(["matmul"])
-
-
-# ------------------------------------------------------ layers integration
-def test_layers_route_rmsnorm_through_plane():
-    coord = TuningCoordinator(policy=RegenerationPolicy(1.0, 0.5),
-                              device="test:r")
-    plane = KernelTuningPlane(coord)
-    from repro.models import layers
-
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 16), jnp.float32)
-    w = jnp.ones((16,), jnp.float32)
-    ref = layers.rms_norm(x, w)
-    assert active_plane() is None
-    with use_kernel_plane(plane):
-        out = layers.rms_norm(x, w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-    (m,) = coord._managed
-    assert m.name == "rmsnorm"
-    assert m.tuner.accounts.kernel_calls == 1
-    # inside a jit trace the plane must NOT intercept (tracer args)…
-    jitted = jax.jit(lambda x, w: layers.rms_norm(x, w))
-    with use_kernel_plane(plane):
-        out2 = jitted(x, w)
-    np.testing.assert_allclose(np.asarray(out2), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-    # …so no new handle appeared and no extra managed call was counted
-    assert len(coord._managed) == 1
-    assert m.tuner.accounts.kernel_calls == 1
-    coord.close()
-
-
-def test_traced_programs_adopt_tuned_attention_chunks():
-    """Trace-time half of the plane: a jitted step-program picks up the
-    attention kernel's best block sizes instead of cfg's hard-coded
-    chunks — unless a program-level tuner owns those knobs."""
-    from repro.configs import REGISTRY
-    from repro.models.layers import plane_attn_chunks
-
-    cfg = REGISTRY["deepseek-7b"].reduced()
-    clock = VirtualClock()
-    coord = TuningCoordinator(policy=RegenerationPolicy(1.0, 0.5),
-                              device="test:v", clock=clock)
-    plane = make_virtual_plane(clock, coord)
-    h = plane.register_spec("attention", SPECS["attention"])
-    for i in range(2000):
-        h(i)
-        coord.pump()
-        if h.tuner.explorer.finished:
-            break
-    best = h.tuner.explorer.best_point
-    assert best is not None
-    assert plane_attn_chunks(cfg) == (cfg.attn_q_chunk, cfg.attn_k_chunk)
-    with use_kernel_plane(plane):
-        assert plane_attn_chunks(cfg) == (best["block_q"],
-                                          best["block_kv"])
-    # "both" mode: program points own the chunk knobs — no adoption
-    plane.adopt_points = False
-    with use_kernel_plane(plane):
-        assert plane_attn_chunks(cfg) == (cfg.attn_q_chunk,
-                                          cfg.attn_k_chunk)
 
 
 # ------------------------------------------------- source-hash identity

@@ -5,6 +5,7 @@ the real (reduced) model end-to-end to show bucketing/eviction on the
 actual request path.
 """
 
+import dataclasses
 import os
 import tempfile
 
@@ -304,139 +305,105 @@ def test_registry_records_strategy_provenance():
 
 
 # ------------------------------------------------------------- serve loop
+def _serve(max_new_tokens, **tuning):
+    """A ServeConfig with program tuning on under the serving defaults,
+    and a session of that configuration."""
+    from repro.api import TuningSession, serve_tuning_defaults
+    from repro.runtime.serve_loop import ServeConfig
+
+    tcfg = dataclasses.replace(serve_tuning_defaults(), enabled=True,
+                               **tuning)
+    return (ServeConfig(max_new_tokens=max_new_tokens, tuning=tcfg),
+            TuningSession(tcfg))
+
+
 @pytest.mark.parametrize("strategy", ["two_phase", "greedy"])
 def test_serve_requests_share_bucketed_prefill_tuner(strategy):
     """Acceptance: prompts of length 120 and 150 (same pow2 bucket, 128)
     must share ONE prefill tuner instead of spawning one per shape."""
-    from repro.runtime.serve_loop import (
-        ServeConfig, generate, make_serve_coordinator)
+    from repro.runtime.serve_loop import generate
 
     cfg = REGISTRY["deepseek-7b"].reduced()
-    serve = ServeConfig(max_new_tokens=4, autotune=True,
-                        tune_max_overhead=0.5, tune_strategy=strategy,
-                        seq_buckets=True, idle_evict_s=None)
-    coordinator = make_serve_coordinator(serve)
-    for seq in (120, 150):
-        batch = {"tokens": jnp.ones((2, seq), jnp.int32)}
-        out = generate(cfg, batch, serve, coordinator=coordinator)
-        assert out["tokens"].shape == (2, 4)
-    stats = out["autotune"]
-    prefill_keys = [k for k in stats["kernels"] if "serve_prefill" in k]
-    assert len(prefill_keys) == 1, stats["kernels"].keys()
-    pf = stats["kernels"][prefill_keys[0]]
-    assert pf["strategy"] == strategy
-    # both requests' prefill calls landed on the shared tuner
-    assert pf["kernel_calls"] == 2
-    # the tuner is keyed by the bucket, not either raw length
-    (m,) = [m for m in coordinator._managed if m.name == "serve_prefill"]
-    assert m.specialization["seq"] == 128
-    # init-time reference measurements are surfaced (and budgeted)
-    assert stats["init_spent_s"] > 0
-    assert stats["budget_spent_s"] >= stats["init_spent_s"]
+    serve, session = _serve(4, max_overhead=0.5, strategy=strategy,
+                            seq_buckets=True, idle_evict_s=None)
+    try:
+        for seq in (120, 150):
+            batch = {"tokens": jnp.ones((2, seq), jnp.int32)}
+            out = generate(cfg, batch, serve, session=session)
+            assert out["tokens"].shape == (2, 4)
+        stats = out["autotune"]
+        prefill_keys = [k for k in stats["kernels"] if "serve_prefill" in k]
+        assert len(prefill_keys) == 1, stats["kernels"].keys()
+        pf = stats["kernels"][prefill_keys[0]]
+        assert pf["strategy"] == strategy
+        # both requests' prefill calls landed on the shared tuner
+        assert pf["kernel_calls"] == 2
+        # the tuner is keyed by the bucket, not either raw length
+        (m,) = [m for m in session.coordinator._managed
+                if m.name == "serve_prefill"]
+        assert m.specialization["seq"] == 128
+        # init-time reference measurements are surfaced (and budgeted)
+        assert stats["init_spent_s"] > 0
+        assert stats["budget_spent_s"] >= stats["init_spent_s"]
+    finally:
+        session.close()
 
 
 def test_serve_unbucketed_accumulates_tuners():
     """Control: with bucketing off, the same traffic spawns one prefill
     tuner per exact shape (the leak the lifecycle exists to stop)."""
-    from repro.runtime.serve_loop import (
-        ServeConfig, generate, make_serve_coordinator)
+    from repro.runtime.serve_loop import generate
 
     cfg = REGISTRY["deepseek-7b"].reduced()
-    serve = ServeConfig(max_new_tokens=4, autotune=True,
-                        tune_max_overhead=0.5, seq_buckets=False,
-                        idle_evict_s=None)
-    coordinator = make_serve_coordinator(serve)
-    for seq in (120, 150):
-        batch = {"tokens": jnp.ones((2, seq), jnp.int32)}
-        out = generate(cfg, batch, serve, coordinator=coordinator)
+    serve, session = _serve(4, max_overhead=0.5, seq_buckets=False,
+                            idle_evict_s=None)
+    try:
+        for seq in (120, 150):
+            batch = {"tokens": jnp.ones((2, seq), jnp.int32)}
+            out = generate(cfg, batch, serve, session=session)
+    finally:
+        session.close()
     stats = out["autotune"]
     prefill_keys = [k for k in stats["kernels"] if "serve_prefill" in k]
     assert len(prefill_keys) == 2
 
 
-def test_serve_hierarchical_registration_both_levels():
-    """Acceptance (e2e): kernel_tuning="both" registers the step-programs
-    AND their constituent matmul/attention/rmsnorm kernels as independent
-    coordinator-managed compilettes — each with its own strategy — under
-    one shared budget, with per-kernel accounting that sums consistently
-    into the aggregate."""
-    from repro.runtime.serve_loop import (
-        ServeConfig, generate, make_serve_coordinator)
-
-    cfg = REGISTRY["deepseek-7b"].reduced()
-    serve = ServeConfig(max_new_tokens=4, autotune=True,
-                        tune_max_overhead=0.5, kernel_tuning="both",
-                        kernel_strategies={"attention": "greedy"},
-                        seq_buckets=True, idle_evict_s=None)
-    coordinator = make_serve_coordinator(serve)
-    try:
-        batch = {"tokens": jnp.ones((2, 24), jnp.int32)}
-        out = generate(cfg, batch, serve, coordinator=coordinator)
-        assert out["tokens"].shape == (2, 4)
-        assert out["kernel_tuning"] == "both"
-        stats = out["autotune"]
-        names = {m.name for m in coordinator._managed}
-        assert {"serve_prefill", "serve_decode",
-                "matmul", "attention", "rmsnorm"} <= names
-        # per-kernel strategy beside the coordinator default
-        assert stats["kernels"]["attention"]["strategy"] == "greedy"
-        assert stats["kernels"]["matmul"]["strategy"] == "two_phase"
-        # every kernel is an independent compilette with its own space
-        specs = {m.name: m.tuner.compilette.space for m in
-                 coordinator._managed}
-        assert specs["matmul"] is not specs["attention"]
-        # per-kernel accounting rolls up into the aggregate exactly
-        for f in ("gen_spent_s", "gen_stall_s", "eval_spent_s"):
-            rollup = (sum(k[f] for k in stats["kernels"].values())
-                      + stats["retired_accounts"][f])
-            assert rollup == pytest.approx(stats[f]), f
-    finally:
-        coordinator.close()
-
-
-def test_serve_kernel_only_mode_skips_step_programs():
-    """kernel_tuning="kernel": only the constituent kernels register; the
-    un-managed step-programs still credit busy time to the shared
-    budget (a busy-time policy would otherwise starve kernel tuning)."""
-    from repro.runtime.serve_loop import (
-        ServeConfig, generate, make_serve_coordinator)
-
-    cfg = REGISTRY["deepseek-7b"].reduced()
-    serve = ServeConfig(max_new_tokens=4, autotune=True,
-                        tune_max_overhead=0.5, kernel_tuning="kernel",
-                        seq_buckets=True, idle_evict_s=None)
-    coordinator = make_serve_coordinator(serve)
-    try:
-        batch = {"tokens": jnp.ones((2, 24), jnp.int32)}
-        out = generate(cfg, batch, serve, coordinator=coordinator)
-        names = {m.name for m in coordinator._managed}
-        assert "serve_prefill" not in names and "serve_decode" not in names
-        assert {"matmul", "attention", "rmsnorm"} <= names
-        # the step-programs' real traffic accrued busy-time budget
-        assert out["autotune"]["busy_s"] > 0
-        assert coordinator._external_busy_s > 0
-    finally:
-        coordinator.close()
-
-
-def test_serve_rejects_unknown_kernel_tuning_mode():
+def test_generate_tunes_exactly_the_step_programs():
+    """One tuning level: a tuned request registers the prefill and decode
+    step programs and nothing else (no kernel plane is built), and serves
+    the tokens a request with tuning off serves."""
     from repro.runtime.serve_loop import ServeConfig, generate
 
     cfg = REGISTRY["deepseek-7b"].reduced()
-    serve = ServeConfig(max_new_tokens=2, kernel_tuning="bogus")
-    with pytest.raises(ValueError, match="kernel_tuning"):
-        generate(cfg, {"tokens": jnp.ones((1, 8), jnp.int32)}, serve)
+    tokens = jnp.arange(2 * 24, dtype=jnp.int32).reshape(2, 24) % cfg.vocab
+    plain = generate(cfg, {"tokens": tokens}, ServeConfig(max_new_tokens=4))
+    serve, session = _serve(4, max_overhead=0.5, idle_evict_s=None)
+    try:
+        out = generate(cfg, {"tokens": tokens}, serve, session=session)
+        names = sorted(m.name for m in session.coordinator._managed)
+        assert names == ["serve_decode", "serve_prefill"]
+        assert session.plane is None
+        assert not any(k.get("plane_managed")
+                       for k in out["autotune"]["kernels"].values())
+    finally:
+        session.close()
+    assert (out["tokens"] == plain["tokens"]).all()
 
 
 def test_serve_kernel_tuning_off_disables_autotune():
-    """kernel_tuning="off" wins over autotune=True: no tuners, no
-    "autotune" stats block (the CLIs key their report off its absence)."""
-    from repro.runtime.serve_loop import ServeConfig, generate
+    """Tuning off (kernel_tuning="off" wins over enabled=True): no tuners
+    register, and no "autotune" stats block (the CLIs key their report
+    off its absence)."""
+    from repro.runtime.serve_loop import generate
 
     cfg = REGISTRY["deepseek-7b"].reduced()
-    serve = ServeConfig(max_new_tokens=2, autotune=True,
-                        kernel_tuning="off")
-    out = generate(cfg, {"tokens": jnp.ones((1, 8), jnp.int32)}, serve)
+    serve, session = _serve(2, kernel_tuning="off")
+    try:
+        out = generate(cfg, {"tokens": jnp.ones((1, 8), jnp.int32)}, serve,
+                       session=session)
+        assert session.coordinator._managed == []
+    finally:
+        session.close()
     assert out["tokens"].shape == (1, 2)
     assert "autotune" not in out
 
@@ -444,31 +411,32 @@ def test_serve_kernel_tuning_off_disables_autotune():
 def test_serve_idle_tuner_evicted_between_requests():
     """Acceptance: a tuner idle past the eviction horizon is unregistered
     at the next request's lifecycle pass, its evaluator closure released."""
-    from repro.runtime.serve_loop import (
-        ServeConfig, generate, make_serve_coordinator)
+    from repro.runtime.serve_loop import generate
 
     import time
 
     cfg = REGISTRY["deepseek-7b"].reduced()
-    serve = ServeConfig(max_new_tokens=4, autotune=True,
-                        tune_max_overhead=0.5, seq_buckets=True,
-                        idle_evict_s=None)
-    coordinator = make_serve_coordinator(serve)
+    serve, session = _serve(4, max_overhead=0.5, seq_buckets=True,
+                            idle_evict_s=None)
+    coordinator = session.coordinator
     batch = {"tokens": jnp.ones((2, 12), jnp.int32)}
-    generate(cfg, batch, serve, coordinator=coordinator)
-    managed_before = list(coordinator._managed)
-    assert managed_before
-    # the server goes quiet: shrink the horizon so the idle gap between
-    # requests crosses it, then run the next lifecycle pass
-    coordinator.lifecycle.idle_evict_s = 1e-6
-    time.sleep(0.002)
-    retired = coordinator.sweep()
-    assert set(retired) == set(managed_before)
-    for m in retired:
-        assert m.state is TunerState.RETIRED
-        assert m.tuner.evaluator.make_args is None
-    assert coordinator.stats()["n_kernels"] == 0
-    assert coordinator.stats()["lifecycle"]["retired"] == len(retired)
-    # traffic returning later re-registers cleanly (warm from registry)
-    out = generate(cfg, batch, serve, coordinator=coordinator)
-    assert out["tokens"].shape == (2, 4)
+    try:
+        generate(cfg, batch, serve, session=session)
+        managed_before = list(coordinator._managed)
+        assert managed_before
+        # the server goes quiet: shrink the horizon so the idle gap
+        # between requests crosses it, then run the next lifecycle pass
+        coordinator.lifecycle.idle_evict_s = 1e-6
+        time.sleep(0.002)
+        retired = coordinator.sweep()
+        assert set(retired) == set(managed_before)
+        for m in retired:
+            assert m.state is TunerState.RETIRED
+            assert m.tuner.evaluator.make_args is None
+        assert coordinator.stats()["n_kernels"] == 0
+        assert coordinator.stats()["lifecycle"]["retired"] == len(retired)
+        # traffic returning later re-registers cleanly (warm from registry)
+        out = generate(cfg, batch, serve, session=session)
+        assert out["tokens"].shape == (2, 4)
+    finally:
+        session.close()

@@ -6,7 +6,9 @@ the absence of NaNs. Decode paths are checked for consistency against a
 longer prefill.
 """
 
+import ast
 import dataclasses
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,12 @@ from repro.models.model import build_model
 from repro.models.params import count_params, init_tree
 
 ARCHS = sorted(REGISTRY)
+MODELS_DIR = pathlib.Path(__file__).resolve().parent.parent / "src" / \
+    "repro" / "models"
+MODEL_MODULES = sorted(p.stem for p in MODELS_DIR.glob("*.py"))
+# the layers above the models: the tuner, the front door, the benchmark
+# harnesses and the launchers
+UPPER_LAYERS = ("repro.runtime", "repro.api", "repro.bench", "repro.launch")
 
 
 def make_batch(cfg, B=2, T=32, key=jax.random.PRNGKey(1)):
@@ -174,3 +182,31 @@ def test_rope_pair_locality():
     np.testing.assert_allclose(part[..., :2], full[..., :2], rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(part[..., 2:], jnp.zeros_like(part[..., 2:]),
                                atol=1e-6)
+
+
+def _imported_modules(tree: ast.AST) -> list[str]:
+    """Every module an AST of a ``repro.models`` module imports, at top
+    level or inside functions, relative imports resolved."""
+    names = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names += [alias.name for alias in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = node.module or ""
+            if node.level:
+                package = ["repro", "models"][:3 - node.level]
+                base = ".".join(package + ([base] if base else []))
+            names.append(base)
+            names += [f"{base}.{alias.name}" for alias in node.names]
+    return names
+
+
+@pytest.mark.parametrize("module", MODEL_MODULES)
+def test_model_modules_import_no_tuner(module):
+    """The model layer builds programs from its ModelConfig alone: no
+    module under repro.models imports the tuner or anything above it."""
+    tree = ast.parse((MODELS_DIR / f"{module}.py").read_text())
+    upward = [name for name in _imported_modules(tree)
+              if any(name == layer or name.startswith(layer + ".")
+                     for layer in UPPER_LAYERS)]
+    assert upward == [], f"repro.models.{module} imports {upward}"

@@ -222,7 +222,7 @@ def test_generate_counts_routing_once_per_request():
     B, n = 3, 5
     before = telemetry.snapshot()
     out = generate(mc, {"tokens": tokens(B, 12), "params": params},
-                   ServeConfig(max_new_tokens=n, autotune=False))
+                   ServeConfig(max_new_tokens=n))
     after = telemetry.snapshot()
     d = {k: after[k]["n"] - before.get(k, {"n": 0})["n"]
          for k in ("moe.rows", "moe.active")}
@@ -232,7 +232,7 @@ def test_generate_counts_routing_once_per_request():
     dense = get_config("deepseek-7b").reduced()
     before = telemetry.snapshot()
     generate(dense, {"tokens": tokens(1, 8)},
-             ServeConfig(max_new_tokens=3, autotune=False))
+             ServeConfig(max_new_tokens=3))
     after = telemetry.snapshot()
     assert all(after.get(k) == before.get(k) for k in ("moe.rows",
                                                       "moe.active"))

@@ -1,6 +1,7 @@
 """Substrate tests: optimizer, data, checkpointing, fault tolerance,
 gradient compression, train/serve loops."""
 
+import dataclasses
 import os
 import tempfile
 
@@ -9,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.api import train_tuning_defaults
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import REGISTRY
 from repro.configs.base import ShapeSpec
@@ -168,8 +170,10 @@ def test_train_autotune_respects_budget_and_persists():
     with tempfile.TemporaryDirectory() as d:
         cfg = REGISTRY["deepseek-7b"].reduced()
         out = train(cfg, SMOKE_SHAPE, TrainLoopConfig(
-            steps=20, ckpt_every=10, ckpt_dir=d, autotune=True,
-            tune_max_overhead=0.5, tune_invest=0.5))
+            steps=20, ckpt_every=10, ckpt_dir=d,
+            tuning=dataclasses.replace(
+                train_tuning_defaults(), enabled=True, max_overhead=0.5,
+                invest=0.5)))
         stats = out["autotune"]
         assert stats["regenerations"] >= 1
         assert os.path.exists(os.path.join(d, "tuned.json"))

@@ -186,15 +186,15 @@ def test_evaluate_waits_for_its_inputs_inside_its_span():
 
 # ------------------------------------------------------------- generate
 def test_generate_opens_its_spans_once_each():
-    from repro.api import TuningSession
+    from repro.api import TuningSession, serve_tuning_defaults
     from repro.configs import REGISTRY
     from repro.runtime.serve_loop import ServeConfig, generate
 
     cfg = REGISTRY["deepseek-7b"].reduced()
     new = 9
-    serve = ServeConfig(max_new_tokens=new, autotune=True,
-                        tune_max_overhead=0.5, kernel_tuning="program",
-                        idle_evict_s=None)
+    serve = ServeConfig(max_new_tokens=new, tuning=dataclasses.replace(
+        serve_tuning_defaults(), enabled=True, max_overhead=0.5,
+        idle_evict_s=None))
     session = TuningSession(serve.tuning)
     pumps = []
     pump = session.coordinator.pump
@@ -237,7 +237,7 @@ def test_generate_counts_the_prefill_kernel_tiles(monkeypatch):
     program's blocks."""
     import types
 
-    from repro.api import TuningSession
+    from repro.api import TuningSession, serve_tuning_defaults
     from repro.configs import REGISTRY
     from repro.kernels.attention.ops import causal_tiles
     from repro.runtime import serve_loop
@@ -247,8 +247,8 @@ def test_generate_counts_the_prefill_kernel_tiles(monkeypatch):
     cfg = REGISTRY["deepseek-7b"].reduced()
     B, T = 2, 100
     batch = {"tokens": jnp.ones((B, T), jnp.int32)}
-    serve = ServeConfig(max_new_tokens=2, autotune=True,
-                        kernel_tuning="program", idle_evict_s=None)
+    serve = ServeConfig(max_new_tokens=2, tuning=dataclasses.replace(
+        serve_tuning_defaults(), enabled=True, idle_evict_s=None))
     session = TuningSession(serve.tuning)
     try:
         before = telemetry.snapshot()
@@ -261,7 +261,7 @@ def test_generate_counts_the_prefill_kernel_tiles(monkeypatch):
 
     monkeypatch.setattr(serve_loop, "_platform", lambda params: "tpu")
     before = telemetry.snapshot()
-    generate(cfg, dict(batch), ServeConfig(max_new_tokens=2, autotune=False))
+    generate(cfg, dict(batch), ServeConfig(max_new_tokens=2))
     after = telemetry.snapshot()
     calls = cfg.n_layers * B * cfg.n_kv_heads
     grid, run = causal_tiles(T, T, cfg.attn_q_chunk, cfg.attn_k_chunk)
